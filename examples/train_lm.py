@@ -85,11 +85,13 @@ def main():
     for step in range(start, args.steps):
         batch = {k: jnp.asarray(v) for k, v in data.batch_at(step).items()}
         with Timer() as t:
-            new_params, new_opt, metrics = step_fn(params, opt_state, batch)
+            # The step applies its update only below the guard's limit.
+            params, opt_state, metrics = step_fn(
+                params, opt_state, batch, np.float32(guard.max_loss()))
             jax.block_until_ready(metrics["loss"])
         loss = float(metrics["loss"])
         gn = float(metrics["grad_norm"])
-        action = guard.check(loss, gn)
+        action = guard.record(bool(metrics["applied"]), loss)
         if action == "skip":
             print(f"step {step}: DIVERGENCE ({loss=:.3g} {gn=:.3g}) — "
                   f"update skipped")
@@ -101,7 +103,6 @@ def main():
                 params, opt_state = restored["params"], restored["opt"]
                 print(f"step {step}: restored checkpoint from step {s}")
             continue
-        params, opt_state = new_params, new_opt
         st = monitor.record(step, t.seconds, loss, gn)
         losses.append(loss)
         if step % 20 == 0 or step == args.steps - 1:

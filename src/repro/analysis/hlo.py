@@ -92,15 +92,6 @@ def collective_bytes(hlo_text: str) -> int:
 
 
 def xla_cost_dict(cost_analysis) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns ``[dict]`` (one entry per program), newer returns the
-    dict directly; either may be None for backends without an implementation.
-    """
-    if cost_analysis is None:
-        return {}
-    if isinstance(cost_analysis, dict):
-        return cost_analysis
-    if isinstance(cost_analysis, (list, tuple)):
-        return dict(cost_analysis[0]) if cost_analysis else {}
-    return dict(cost_analysis)
+    """``compiled.cost_analysis()`` as a dict (empty where the backend has
+    no cost model and returns None)."""
+    return dict(cost_analysis or {})

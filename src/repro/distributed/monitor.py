@@ -69,20 +69,30 @@ class StragglerMonitor:
 class DivergenceGuard:
     """NaN/inf and loss-spike detection with skip/restore policy.
 
-    ``check`` returns the action for this step: "ok", "skip" (drop the
+    ``record`` returns the action for this step: "ok", "skip" (drop the
     update), or "restore" (roll back to the last checkpoint) after
-    ``max_skips`` consecutive bad steps."""
+    ``max_skips`` consecutive bad steps.  Where the step itself decides
+    whether to apply its update (``launch.steps.make_train_step`` against
+    ``max_loss()``), the caller passes that verdict to ``record``; ``check``
+    decides on the host instead, by the same rule."""
     spike_factor: float = 10.0
     max_skips: int = 3
     _ewma_loss: float = field(default=0.0, init=False)
     _skips: int = field(default=0, init=False)
 
+    def max_loss(self) -> float:
+        """Largest loss ``check`` accepts as a good step right now."""
+        return (self.spike_factor * self._ewma_loss if self._ewma_loss > 0
+                else math.inf)
+
     def check(self, loss: float, grad_norm: float) -> str:
-        bad = (math.isnan(loss) or math.isinf(loss)
-               or math.isnan(grad_norm) or math.isinf(grad_norm))
-        if not bad and self._ewma_loss > 0:
-            bad = loss > self.spike_factor * self._ewma_loss
-        if bad:
+        good = (math.isfinite(loss) and math.isfinite(grad_norm)
+                and loss <= self.max_loss())
+        return self.record(good, loss)
+
+    def record(self, applied: bool, loss: float) -> str:
+        """Count a step whose update was (or was not) applied."""
+        if not applied:
             self._skips += 1
             return "restore" if self._skips > self.max_skips else "skip"
         self._skips = 0

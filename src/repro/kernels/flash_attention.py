@@ -22,8 +22,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import compiler_params
-
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -41,8 +39,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
-    k_pos = ki * block_k + jax.lax.iota(jnp.int32, block_k)
+    # Positions as 2-D iotas: Mosaic cannot reshape a 1-D boolean vector
+    # into a column, so every mask is built at its final rank.
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
 
     def body():
         q = q_ref[0].astype(jnp.float32) * scale        # [bq, d]
@@ -50,25 +52,26 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # [bq, bk]
-        mask = jnp.ones((block_q, block_k), jnp.bool_)
         # Padding beyond the true sequence end.
-        mask &= (k_pos < seq_kv)[None, :]
+        mask = k_pos < seq_kv
         if causal:
             offs = seq_kv - seq_q  # queries start at this kv offset
-            mask &= k_pos[None, :] <= (q_pos[:, None] + offs)
+            mask &= k_pos <= q_pos + offs
             if window > 0:
-                mask &= (q_pos[:, None] + offs - k_pos[None, :]) < window
+                mask &= (q_pos + offs - k_pos) < window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                              # [bq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         # Zero padded kv rows explicitly: p is ~0 there, but 0 x junk from
         # the padded tile region is NaN-poisonous in the PV product.
-        v = jnp.where((k_pos < seq_kv)[:, None], v_ref[0], 0)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+        v_row = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, v_ref.shape[1:], 0)
+        v = jnp.where(v_row < seq_kv, v_ref[0], 0)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -88,7 +91,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ki == kv_blocks - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-20)
-        o_ref[0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -139,12 +142,12 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         out_specs=pl.BlockSpec((1, block_q, d), q_map),
         out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=compiler_params(
-            ("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(b, hq, sq, d)

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import compiler_params
-
 
 def _moe_gemm_kernel(x_ref, w_ref, o_ref, acc_scr, *, k_blocks: int):
     ki = pl.program_id(3)
@@ -63,8 +61,8 @@ def moe_grouped_gemm(x, w, *, block_c: int = 128, block_f: int = 128,
                                lambda ei, ci, fi, ki: (ei, ci, fi)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
-        compiler_params=compiler_params(
-            ("parallel", "parallel", "parallel",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(x, w)

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import compiler_params
-
 DEFAULT_CHUNK = 64
 # The separable decay factorization exp(cumW_t)*exp(-cumW_s) is bounded only
 # while |cum log-decay| stays within f32 exponent range; 64 steps of the
@@ -41,9 +39,16 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_scr, *,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     lw = w_ref[0].astype(jnp.float32)         # log decay <= 0
-    u = u_ref[0].astype(jnp.float32)          # [D]
+    u = u_ref[0].astype(jnp.float32)          # [1, D]
 
-    cum = jnp.cumsum(lw, axis=0)              # logW_t   [C, D]
+    # Mosaic has no cumsum: prefix sums over the chunk are a matmul with
+    # the lower-triangular ones matrix, at full f32 precision.
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    exact = jax.lax.Precision.HIGHEST
+    cum = jax.lax.dot_general(
+        (row >= col).astype(jnp.float32), lw, (((1,), (0,)), ((), ())),
+        precision=exact, preferred_element_type=jnp.float32)  # logW_t [C, D]
     cum_prev = cum - lw                       # logW_{t-1}
     state = state_scr[...]                    # [D, D]
 
@@ -58,20 +63,23 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_scr, *,
     ks = k * jnp.exp(-cum)
     att = jax.lax.dot_general(rq, ks, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)  # [C, C]
-    t_idx = jax.lax.iota(jnp.int32, chunk)
-    tri = t_idx[:, None] > t_idx[None, :]
-    att = jnp.where(tri, att, 0.0)
-    diag = jnp.sum(r * u[None, :] * k, axis=1)          # bonus, s == t
+    att = jnp.where(row > col, att, 0.0)
+    diag = jnp.sum(r * u * k, axis=1, keepdims=True)    # bonus, s == t
     intra = jax.lax.dot_general(att, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    intra = intra + diag[:, None] * v
+    intra = intra + diag * v
 
     o_ref[0] = (inter + intra).astype(o_ref.dtype)
 
     # state update: S1 = W_C ⊙ S0 + Σ_s (k_s W_C / W_s) v_s^T
-    wtot = cum[-1]                                       # [D]
-    kdec = k * jnp.exp(wtot[None, :] - cum)              # [C, D]
-    state_scr[...] = state * jnp.exp(wtot)[:, None] + jax.lax.dot_general(
+    wtot = cum[chunk - 1:chunk]                          # [1, D]
+    # The same total as a column (decay of the state's rows), summed
+    # directly rather than transposed.
+    wtot_col = jax.lax.dot_general(
+        lw, jnp.ones((chunk, 1), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=exact, preferred_element_type=jnp.float32)  # [D, 1]
+    kdec = k * jnp.exp(wtot - cum)                       # [C, D]
+    state_scr[...] = state * jnp.exp(wtot_col) + jax.lax.dot_general(
         kdec, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
@@ -93,7 +101,7 @@ def rwkv6_chunk(r, k, v, w_log, u, *, chunk: int = DEFAULT_CHUNK,
         return (b, c, 0)
 
     def u_map(b, c):
-        return (b, 0)
+        return (b, 0, 0)
 
     kernel = functools.partial(_rwkv_kernel, chunk=chunk)
     out = pl.pallas_call(
@@ -104,13 +112,15 @@ def rwkv6_chunk(r, k, v, w_log, u, *, chunk: int = DEFAULT_CHUNK,
             pl.BlockSpec((1, chunk, d), seq_map),
             pl.BlockSpec((1, chunk, d), seq_map),
             pl.BlockSpec((1, chunk, d), seq_map),
-            pl.BlockSpec((1, d), u_map),
+            # [BH, 1, D]: a (1, D) block of a 2-D [BH, D] array breaks the
+            # TPU rule that a block's last two dims tile by (8, 128).
+            pl.BlockSpec((1, 1, d), u_map),
         ],
         out_specs=pl.BlockSpec((1, chunk, d), seq_map),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=compiler_params(
-            ("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, w_log, u)
+    )(r, k, v, w_log, u[:, None, :])
     return out

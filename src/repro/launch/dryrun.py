@@ -125,7 +125,9 @@ def build_cell(arch: str, shape: str, mesh, overrides=None, remat="full",
             # Donate params/opt-state: in-place update, halves live bytes.
             jitted = jax.jit(step, out_shardings=(p_sh, o_sh, None),
                              donate_argnums=(0, 1))
-            lowered = jitted.lower(p_structs, o_structs, b_structs)
+            max_loss = jax.ShapeDtypeStruct(
+                (), jnp.float32, sharding=NamedSharding(mesh, P()))
+            lowered = jitted.lower(p_structs, o_structs, b_structs, max_loss)
             mf = model_flops_train(cfg, spec["batch"] * spec["seq"])
             cost_scale = ga
         elif spec["kind"] == "prefill":

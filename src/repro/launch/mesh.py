@@ -27,9 +27,15 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
 
 
-def make_host_mesh(model_axis: int = 1) -> Mesh:
-    """Tiny mesh over whatever devices exist (CPU tests / examples)."""
+def make_host_mesh(model_axis: int = 1, n_devices: int = 0) -> Mesh:
+    """Mesh over this host's devices: all of them, or the first
+    ``n_devices`` (a one-device reference run on a multi-chip host)."""
     devices = jax.devices()
+    if n_devices:
+        if n_devices > len(devices):
+            raise ValueError(f"asked for {n_devices} devices, have "
+                             f"{len(devices)}")
+        devices = devices[:n_devices]
     n = len(devices)
     model_axis = min(model_axis, n)
     data_axis = n // model_axis

@@ -18,6 +18,7 @@ from __future__ import annotations
 import argparse
 import time
 from collections import deque
+from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
@@ -25,12 +26,27 @@ import numpy as np
 
 from repro import configs
 from repro.distributed.sharding import mesh_context
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import make_serve_step
 from repro.models import model as M
 
 
-def main(argv=None):
+@dataclass
+class ServeSummary:
+    """What one ``main`` run served: the prompt and the generated token ids
+    per request id (completed requests only in ``completed``), the batched
+    decode steps taken and their wall time (first step's compilation
+    included)."""
+    requests: int
+    prompts: dict[int, np.ndarray] = field(default_factory=dict)
+    completed: dict[int, list[int]] = field(default_factory=dict)
+    steps: int = 0
+    seconds: float = 0.0
+
+
+def main(argv=None) -> ServeSummary:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--smoke", action="store_true")
@@ -107,6 +123,7 @@ def main(argv=None):
     queue = deque(
         (i, rng.integers(0, cfg.vocab, (int(rng.integers(4, 12)),))
          .astype(np.int32)) for i in range(args.requests))
+    prompts = dict(queue)
 
     with mesh_context(mesh):
         key = jax.random.PRNGKey(0)
@@ -305,6 +322,8 @@ def main(argv=None):
             if args.offload_sweep:
                 _offload_sweep(log, args.device_fracs, args.host_fracs,
                                args.offload_bw)
+    return ServeSummary(requests=args.requests, prompts=prompts,
+                        completed=completed, steps=steps, seconds=dt)
 
 
 def _offload_sweep(log, device_fracs, host_fracs, bw_rel,

@@ -33,12 +33,20 @@ from ..optim.optimizers import Optimizer
 
 def make_train_step(cfg: ModelConfig, opt: Optimizer, grad_accum: int = 1,
                     max_grad_norm: float = 1.0):
-    """Returns train_step(params, opt_state, batch) -> (params, state, metrics)."""
+    """Returns train_step(params, opt_state, batch, max_loss) ->
+    (params, state, metrics).
+
+    The update is applied on the device only when the loss and grad norm
+    are finite and the loss is at most ``max_loss`` (a float32 scalar; inf
+    accepts every finite step); otherwise the step returns the state it was
+    given, and ``metrics["applied"]`` says which.  A caller that donates the
+    state can then drop a bad step without the old buffers, which donation
+    has already released."""
 
     def loss_of(p, b):
         return M.loss_fn(cfg, p, b)
 
-    def train_step(params, opt_state, batch):
+    def train_step(params, opt_state, batch, max_loss):
         if grad_accum > 1:
             def micro(b):
                 return jax.tree.map(
@@ -66,10 +74,14 @@ def make_train_step(cfg: ModelConfig, opt: Optimizer, grad_accum: int = 1,
             loss, grads = jax.value_and_grad(loss_of)(params, batch)
 
         grads, gnorm = clip_by_global_norm(grads, max_grad_norm)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        params = apply_updates(params, updates)
-        metrics = {"loss": loss.astype(jnp.float32), "grad_norm": gnorm}
-        return params, opt_state, metrics
+        updates, new_opt_state = opt.update(grads, opt_state, params)
+        new_params = apply_updates(params, updates)
+        loss = loss.astype(jnp.float32)
+        ok = jnp.isfinite(loss) & jnp.isfinite(gnorm) & (loss <= max_loss)
+        keep = partial(jax.tree.map, lambda n, o: jnp.where(ok, n, o))
+        metrics = {"loss": loss, "grad_norm": gnorm, "applied": ok}
+        return (keep(new_params, params), keep(new_opt_state, opt_state),
+                metrics)
 
     return train_step
 

@@ -17,7 +17,9 @@ guard with restore, straggler monitoring.
 from __future__ import annotations
 
 import argparse
-import time
+import sys
+from dataclasses import dataclass, field
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,19 +31,46 @@ from repro.data.pipeline import Prefetcher, SyntheticLM
 from repro.distributed.monitor import (DivergenceGuard, MemoryMonitor,
                                        StragglerMonitor, Timer)
 from repro.distributed.sharding import mesh_context
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
-from repro.launch.steps import make_train_step, state_shardings
+from repro.launch.steps import (batch_shardings, make_train_step,
+                                state_shardings)
 from repro.models import model as M
 from repro.optim import adafactor, adamw, cosine_schedule
 
 
-def main(argv=None):
+@dataclass
+class TrainSummary:
+    """What one ``main`` run did, step by step.
+
+    ``losses``/``step_seconds`` cover the steps whose update was applied
+    (the first one includes compilation).  ``skipped``/``restored`` list the
+    steps the divergence guard dropped or rolled back; a run with either is
+    not ``clean``.  ``peak_bytes`` is the device allocator's peak, None where
+    the backend reports no memory stats."""
+    steps: list[int] = field(default_factory=list)
+    losses: list[float] = field(default_factory=list)
+    step_seconds: list[float] = field(default_factory=list)
+    skipped: list[int] = field(default_factory=list)
+    restored: list[int] = field(default_factory=list)
+    resumed_from: Optional[int] = None
+    peak_bytes: Optional[int] = None
+
+    @property
+    def clean(self) -> bool:
+        return not self.skipped and not self.restored
+
+
+def main(argv=None) -> TrainSummary:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (CPU-sized)")
     ap.add_argument("--mesh", default="host",
                     choices=["host", "production", "multipod"])
+    ap.add_argument("--devices", type=int, default=0,
+                    help="host mesh over the first N devices (0 = all)")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -63,7 +92,7 @@ def main(argv=None):
     if args.smoke:
         cfg = cfg.replace(dtype="float32")
 
-    mesh = {"host": make_host_mesh,
+    mesh = {"host": lambda: make_host_mesh(n_devices=args.devices),
             "production": make_production_mesh,
             "multipod": lambda: make_production_mesh(multi_pod=True)}[
         args.mesh]()
@@ -72,14 +101,18 @@ def main(argv=None):
     opt = (adafactor(lr=args.lr) if args.optimizer == "adafactor"
            else adamw(lr=cosine_schedule(args.lr, warmup=20,
                                          total=args.steps)))
+    summary = TrainSummary()
 
     with mesh_context(mesh, overrides=overrides, fsdp=args.fsdp):
         key = jax.random.PRNGKey(0)
-        params = M.init_params(cfg, key)
-        opt_state = opt.init(params)
         p_sh, o_sh = state_shardings(cfg, mesh, opt.name, fsdp=args.fsdp)
-        params = jax.device_put(params, p_sh)
-        opt_state = jax.device_put(opt_state, o_sh)
+        # Initialized in place, shard by shard: built eagerly, the whole
+        # unsharded state would first land on the default device.
+        params = jax.jit(lambda k: M.init_params(cfg, k),
+                         out_shardings=p_sh)(key)
+        opt_state = jax.jit(opt.init, out_shardings=o_sh)(params)
+        # The step keeps the old state itself when it rejects an update,
+        # so the donated buffers are never needed again on the host.
         step_fn = jax.jit(
             make_train_step(cfg, opt, grad_accum=args.grad_accum),
             out_shardings=(p_sh, o_sh, None), donate_argnums=(0, 1))
@@ -90,6 +123,7 @@ def main(argv=None):
 
         data = SyntheticLM(vocab=cfg.vocab, seq_len=args.seq,
                            batch=args.batch, n_codebooks=cfg.n_codebooks)
+        b_sh = batch_shardings(cfg, mesh, data.batch_at(0))
         ckpt = CheckpointManager(args.ckpt_dir,
                                  every_steps=args.ckpt_every, keep=2)
         monitor = StragglerMonitor()
@@ -100,12 +134,13 @@ def main(argv=None):
         dev0 = jax.local_devices()[0]
 
         def device_memory():
-            """(peak_bytes, frag_stats|None) from the device allocator.
+            """(peak_bytes|None, frag_stats|None) from the device allocator.
 
             ``memory_stats`` exposes largest_free_block on TPU/GPU backends;
-            CPU returns None — telemetry degrades to peak-bytes only."""
-            stats = (dev0.memory_stats() or {}
-                     if hasattr(dev0, "memory_stats") else {})
+            CPU returns None — telemetry degrades to no numbers."""
+            stats = dev0.memory_stats()
+            if stats is None:
+                return None, None
             peak = stats.get("peak_bytes_in_use", 0)
             frag = None
             if "largest_free_block_bytes" in stats:
@@ -125,6 +160,7 @@ def main(argv=None):
             params = jax.device_put(restored["params"], p_sh)
             opt_state = jax.device_put(restored["opt"], o_sh)
             start += 1
+            summary.resumed_from = start
             print(f"resumed at step {start}")
         else:
             start = 0
@@ -133,17 +169,21 @@ def main(argv=None):
         try:
             for step in range(start, args.steps):
                 _, host_batch = prefetch.next()
-                batch = {k: jnp.asarray(v) for k, v in host_batch.items()}
+                batch = jax.device_put(host_batch, b_sh)
                 with Timer() as t:
-                    new_p, new_o, metrics = step_fn(params, opt_state, batch)
+                    params, opt_state, metrics = step_fn(
+                        params, opt_state, batch,
+                        np.float32(guard.max_loss()))
                     jax.block_until_ready(metrics["loss"])
                 loss = float(metrics["loss"])
                 gn = float(metrics["grad_norm"])
-                action = guard.check(loss, gn)
+                action = guard.record(bool(metrics["applied"]), loss)
                 if action == "skip":
+                    summary.skipped.append(step)
                     print(f"step {step}: bad step ({loss=:.3g}) — skipped")
                     continue
                 if action == "restore":
+                    summary.restored.append(step)
                     s, restored, _ = ckpt.restore(
                         {"params": params, "opt": opt_state})
                     if s is not None:
@@ -151,10 +191,15 @@ def main(argv=None):
                         opt_state = jax.device_put(restored["opt"], o_sh)
                         print(f"step {step}: restored from {s}")
                     continue
-                params, opt_state = new_p, new_o
+                summary.steps.append(step)
+                summary.losses.append(loss)
+                summary.step_seconds.append(t.seconds)
                 st = monitor.record(step, t.seconds, loss, gn)
                 peak_bytes, frag = device_memory()
-                ms = memmon.record(step, peak_bytes, frag=frag)
+                if peak_bytes is not None:
+                    summary.peak_bytes = max(summary.peak_bytes or 0,
+                                             peak_bytes)
+                ms = memmon.record(step, peak_bytes or 0, frag=frag)
                 if step % 10 == 0 or step == args.steps - 1:
                     mem = (f" mem {peak_bytes/1e6:.0f}MB"
                            if peak_bytes else "")
@@ -175,8 +220,12 @@ def main(argv=None):
                      f" min_free_blk {ms['min_largest_free']/1e6:.0f}MB"
                      f" max_frag {ms['max_frag_ratio']:.2f}")
         print(f"mem summary: peak {ms['peak_bytes']/1e6:.0f}MB" + frag_note)
-    print("done")
+    bad = len(summary.skipped) + len(summary.restored)
+    print("done" if summary.clean else
+          f"done with {bad} bad steps (skipped {summary.skipped}, "
+          f"restored {summary.restored})")
+    return summary
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(0 if main().clean else 1)

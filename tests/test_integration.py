@@ -13,6 +13,8 @@ from repro.models import layers as L
 from repro.models import model as M
 from repro.optim import adamw, apply_updates, clip_by_global_norm
 
+NO_LIMIT = np.float32(np.inf)     # train steps' max_loss: apply every update
+
 
 class TestBlockedPaths:
     """The blocked (flash-style) attention paths must match the plain path
@@ -77,7 +79,7 @@ class TestTrainingConverges:
         losses = []
         for i in range(40):
             batch = {k: jnp.asarray(v) for k, v in data.batch_at(i).items()}
-            params, state, metrics = step(params, state, batch)
+            params, state, metrics = step(params, state, batch, NO_LIMIT)
             losses.append(float(metrics["loss"]))
         assert np.mean(losses[-5:]) < np.mean(losses[:5]) * 0.9, losses[::8]
 
@@ -93,7 +95,7 @@ class TestTrainingConverges:
         def one(ga):
             st = opt.init(params)
             step = jax.jit(make_train_step(cfg, opt, grad_accum=ga))
-            p2, _, m = step(params, st, batch)
+            p2, _, m = step(params, st, batch, NO_LIMIT)
             return m["loss"], p2
 
         l1, p1 = one(1)
@@ -118,7 +120,7 @@ class TestResume:
             for i in range(start, n_steps):
                 batch = {k: jnp.asarray(v)
                          for k, v in data.batch_at(i).items()}
-                params, state, _ = step(params, state, batch)
+                params, state, _ = step(params, state, batch, NO_LIMIT)
             return params, state
 
         p0 = M.init_params(cfg, key)

@@ -159,7 +159,7 @@ def test_ops_adapters():
     q = jax.random.normal(ks[0], (b, s, h, d))
     k = jax.random.normal(ks[1], (b, s, kv, d))
     v = jax.random.normal(ks[2], (b, s, kv, d))
-    a1 = ops.attention(q, k, v, use_kernel=True)
+    a1 = ops.attention(q, k, v, use_kernel=True, interpret=True)
     a2 = ops.attention(q, k, v, use_kernel=False)
     np.testing.assert_allclose(np.asarray(a1), np.asarray(a2),
                                rtol=3e-5, atol=3e-5)
@@ -183,8 +183,16 @@ def test_kernels_compiled_on_accelerator(kernel):
     """Mosaic-compiled kernels must match the same references as interpret.
 
     Skipped on CPU-only hosts (conftest ``requires_accel``); interpret-mode
-    equivalence above covers the kernel bodies everywhere.
+    equivalence above covers the kernel bodies everywhere.  Kernel and
+    reference run with float32 matmuls, as interpret mode on the CPU does:
+    at a TPU's default precision float32 operands are multiplied in bf16.
+    Blocks keep to the TPU's (8, 128) tiling rule.
     """
+    with jax.default_matmul_precision("highest"):
+        _compiled_kernel_matches_reference(kernel)
+
+
+def _compiled_kernel_matches_reference(kernel):
     ks = jax.random.split(jax.random.PRNGKey(7), 5)
     if kernel == "flash":
         q = rand(ks[0], (1, 2, 128, 64), jnp.float32)
@@ -205,7 +213,7 @@ def test_kernels_compiled_on_accelerator(kernel):
     else:
         x = rand(ks[0], (4, 128, 256), jnp.float32)
         w = rand(ks[1], (4, 256, 128), jnp.float32) * 0.05
-        out = moe_grouped_gemm(x, w, block_c=64, block_f=64, block_d=128,
+        out = moe_grouped_gemm(x, w, block_c=64, block_f=128, block_d=128,
                                interpret=False)
         expect = ref.moe_gemm_reference(x, w)
     np.testing.assert_allclose(np.asarray(out, np.float32),

@@ -145,10 +145,7 @@ class TestCompression:
         """Degenerate 1-device psum must be ~identity (quantization only)."""
         mesh = make_host_mesh()
 
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax keeps it in experimental
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         x = jax.random.normal(jax.random.PRNGKey(1), (64,))
