@@ -27,9 +27,12 @@ differ); large sweeps additionally parallelize across processes with
 """
 from __future__ import annotations
 
+import multiprocessing
 import os
 import shutil
 import tempfile
+from concurrent.futures import ProcessPoolExecutor
+from concurrent.futures.process import BrokenProcessPool
 from dataclasses import dataclass, field
 
 from .graph import Log, replay
@@ -303,13 +306,15 @@ def _run_pool(payloads: list[tuple], workers: int) -> list:
     such cell is retried once in an isolated single-worker pool; a cell
     that kills *that* pool too is recorded as a failed ``RunResult``
     (``error_kind="worker"``) and the rest of the sweep completes.
-    """
-    from concurrent.futures import ProcessPoolExecutor
-    from concurrent.futures.process import BrokenProcessPool
 
+    Workers are spawned, never forked: callers (``repro.trace --verify``,
+    ``benchmarks/sweep.py``) run JAX first, and forking a multithreaded
+    JAX process can deadlock the child.
+    """
+    ctx = multiprocessing.get_context("spawn")
     results: list = [None] * len(payloads)
     broken: list[int] = []
-    with ProcessPoolExecutor(max_workers=workers) as pool:
+    with ProcessPoolExecutor(max_workers=workers, mp_context=ctx) as pool:
         futs = [pool.submit(_simulate_task, p) for p in payloads]
         for i, fut in enumerate(futs):
             try:
@@ -318,7 +323,7 @@ def _run_pool(payloads: list[tuple], workers: int) -> list:
                 broken.append(i)
     for i in broken:
         try:
-            with ProcessPoolExecutor(max_workers=1) as solo:
+            with ProcessPoolExecutor(max_workers=1, mp_context=ctx) as solo:
                 results[i] = solo.submit(_simulate_task,
                                          payloads[i]).result()
         except BrokenProcessPool:
@@ -381,21 +386,14 @@ def sweep_parallel(
         runs: list[RunResult] | None = None
         if processes != 0 and len(payloads) > 1:
             try:
-                from concurrent.futures import ProcessPoolExecutor  # noqa: F401
-            except ImportError:
-                pass
-            else:
-                try:
-                    workers = processes or min(len(payloads),
-                                               os.cpu_count() or 1)
-                    runs = _run_pool(payloads, workers)
-                except (OSError, PermissionError):
-                    # Pool bring-up failure (restricted environments):
-                    # redo the whole grid serially — cells are
-                    # deterministic, so results match an undisturbed
-                    # parallel run.  (Worker deaths are handled inside
-                    # _run_pool, per cell.)
-                    runs = None
+                workers = processes or min(len(payloads), os.cpu_count() or 1)
+                runs = _run_pool(payloads, workers)
+            except OSError:
+                # Pool bring-up failure (restricted environments): redo
+                # the whole grid serially — cells are deterministic, so
+                # results match an undisturbed parallel run.  (Worker
+                # deaths are handled inside _run_pool, per cell.)
+                runs = None
         if runs is None:
             runs = [_simulate_task(p) for p in payloads]
     finally:

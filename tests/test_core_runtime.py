@@ -11,7 +11,7 @@ except ImportError:  # hypothesis is optional: property tests skip, rest run
 from repro.core import graphs, simulator
 from repro.core.graph import Log, LogBuilder, replay
 from repro.core.heuristics import ALL_NAMES, HEStar, by_name, make_ablation
-from repro.core.runtime import DTRRuntime, OOMError
+from repro.core.runtime import DTRRuntime, OOMError, ThrashError
 
 
 def run(log: Log, budget: float, heuristic="h_dtr_eq", **kw) -> DTRRuntime:
@@ -272,8 +272,13 @@ class TestTheorems:
 # Property tests: random DAGs
 # ---------------------------------------------------------------------------
 
+#: The thrash bound ``simulator.simulate`` applies (``thrash_factor``): a
+#: run whose compute passes 50x the baseline stops with ``ThrashError``.
+THRASH_FACTOR = 50
+
+
 class TestProperties:
-    @settings(max_examples=25, deadline=None)
+    @settings(max_examples=25, deadline=None, derandomize=True)
     @given(seed=st.integers(0, 10_000), n_ops=st.integers(5, 40),
            frac=st.floats(0.4, 1.0))
     def test_random_dag_invariants(self, seed, n_ops, frac):
@@ -282,11 +287,12 @@ class TestProperties:
         (4) constants never evicted."""
         log = graphs.random_dag(n_ops, seed=seed)
         peak, base_cost = simulator.measure_baseline(log)
-        rt = DTRRuntime(budget=frac * peak, heuristic=by_name("h_dtr_eq"))
+        rt = DTRRuntime(budget=frac * peak, heuristic=by_name("h_dtr_eq"),
+                        compute_limit=THRASH_FACTOR * base_cost)
         try:
             replay(log, rt)
-        except OOMError:
-            return  # infeasible budget is a legal outcome
+        except (OOMError, ThrashError):
+            return  # infeasible or thrashing budgets are legal outcomes
         assert rt.peak_memory <= frac * peak + 1e-6
         assert rt.total_compute >= base_cost - 1e-6
         for t in rt.tensors.values():
@@ -296,7 +302,18 @@ class TestProperties:
             if s.constant and not s.banished:
                 assert s.resident
 
-    @settings(max_examples=15, deadline=None)
+    def test_thrash_bound_ends_runaway_draw(self):
+        """A draw that thrashes for 285,000+ ops without a compute limit
+        stops within a few thousand under the thrash bound."""
+        log = graphs.random_dag(30, seed=8535)
+        peak, base_cost = simulator.measure_baseline(log)
+        rt = DTRRuntime(budget=0.622 * peak, heuristic=by_name("h_dtr_eq"),
+                        compute_limit=THRASH_FACTOR * base_cost)
+        with pytest.raises(ThrashError):
+            replay(log, rt)
+        assert rt.ops_executed < 10_000
+
+    @settings(max_examples=15, deadline=None, derandomize=True)
     @given(seed=st.integers(0, 10_000), n_ops=st.integers(5, 30))
     def test_unconstrained_matches_baseline(self, seed, n_ops):
         """With infinite budget and 'ignore' dealloc, no op ever re-runs."""
@@ -306,7 +323,7 @@ class TestProperties:
         replay(log, rt)
         assert rt.remat_ops == 0
 
-    @settings(max_examples=15, deadline=None)
+    @settings(max_examples=15, deadline=None, derandomize=True)
     @given(seed=st.integers(0, 10_000), frac=st.floats(0.3, 0.9))
     def test_heuristics_agree_on_feasibility_ordering(self, seed, frac):
         """All heuristics complete or OOM; compute is finite when ok."""

@@ -24,7 +24,7 @@ def test_dryrun_single_cell_compiles(tmp_path, mesh):
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", "qwen2-0.5b", "--shape", "decode_32k",
          "--mesh", mesh, "--out", str(tmp_path)],
-        env=env, capture_output=True, text=True, timeout=420)
+        env=env, capture_output=True, text=True, timeout=240)
     assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
     assert "all cells OK" in out.stdout
     tag = f"qwen2-0.5b_decode_32k_{mesh}.json"

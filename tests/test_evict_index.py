@@ -7,6 +7,8 @@ rematerializations, same compute, same peak memory — across every
 heuristic, deallocation policy, memory model, and seed log.  Only
 ``meta_accesses`` may (and should) differ: that is the point.
 """
+import warnings
+
 import pytest
 
 from repro.core import graphs, simulator
@@ -661,6 +663,20 @@ class TestSweepParallel:
         serial = [simulator.sweep(log, h, self.FR) for log in logs for h in hs]
         par = simulator.sweep_parallel(logs, hs, self.FR, processes=2)
         assert self._flat(par) == self._flat(serial)
+
+    def test_pool_after_jax_does_not_fork(self):
+        """A sweep started by a process that has run JAX spawns its workers:
+        forking a multithreaded JAX process can deadlock the child."""
+        import jax.numpy as jnp
+        jnp.ones(4).block_until_ready()
+        logs = [graphs.mlp(depth=4)]
+        hs = ["h_dtr_eq", "h_lru"]
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            par = simulator.sweep_parallel(logs, hs, self.FR, processes=2)
+        serial = [simulator.sweep(logs[0], h, self.FR) for h in hs]
+        assert self._flat(par) == self._flat(serial)
+        assert not [w for w in caught if "os.fork()" in str(w.message)]
 
     def test_serial_fallback_path(self):
         logs = [graphs.mlp(depth=4)]

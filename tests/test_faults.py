@@ -11,7 +11,6 @@ The differential invariants:
 * alloc faults alone can never kill a run (the ladder absorbs them);
 * a recovered *eager* run computes the same numerics as a fault-free one.
 """
-import multiprocessing
 import os
 
 import pytest
@@ -324,9 +323,8 @@ from repro.core.simulator import _simulate_task as _REAL_TASK  # noqa: E402
 
 class TestWorkerDeath:
     def test_dead_worker_fails_only_its_cell(self, monkeypatch):
-        if multiprocessing.get_start_method() != "fork":
-            pytest.skip("needs fork start method to inherit the patched "
-                        "task into pool workers")
+        # The pool looks the task up at submit time, so the patched killer
+        # is pickled by name and imported by each spawned worker.
         from repro.core import simulator
         monkeypatch.setattr(simulator, "_simulate_task", _lru_killer)
         log = graphs.linear_network(24)
