@@ -3,20 +3,40 @@
 Mirrors the paper's PyTorch prototype (Sec. 5):
 
   * every operator call is dispatched through :meth:`DTRContext.call`, which
-    registers the op + measured cost with the DTR runtime, stores a replay
+    registers the op + its cost with the DTR runtime, stores a replay
     closure, and returns :class:`DTRArray` handles;
   * under memory pressure the runtime picks victims via ``h_DTR^eq`` (or any
     heuristic) and the context *actually drops the buffers*;
   * accessing an evicted array triggers recursive rematerialization through
     the stored closures.
 
-Like the prototype, the budget may be exceeded by exactly one allocation
-(op outputs are computed before the eviction pass — Appendix E.1 notes the
-same slack).
+Costs are wall-clock seconds measured once per op signature (the op's name
+and the shapes and dtypes of its DTRArray inputs) in each context: the first call
+with a signature waits for the device, then times the op alone; later calls
+reuse that cost.  With ``use_wallclock_cost=False`` every cost is 1.0 and no
+call waits to time anything.
+
+Dispatch is asynchronous within a bounded window: the outputs of at most
+``_MAX_INFLIGHT`` dispatched ops (first runs and replays alike) may still be
+computing when :meth:`DTRContext.call` or :meth:`DTRContext.fetch` returns;
+one more waits for the oldest.  The host's bookkeeping for the next op thus
+overlaps the device's work on the last ones, and the caller need not sync.
+The window refers to outputs weakly, so it keeps no buffer alive that the
+runtime has freed, nor any after its context is dropped.
+
+Like the prototype, the accounted budget may be exceeded by exactly one
+allocation (op outputs are computed before the eviction pass — Appendix E.1
+notes the same slack).  Real device memory may exceed it further by what the
+in-flight ops hold until they finish: their outputs, the temporaries inside
+their closures, and inputs evicted after they were dispatched.  The
+allocator's ``peak_bytes_in_use`` counts these but not the temporaries of
+compiled programs.
 """
 from __future__ import annotations
 
 import time
+import weakref
+from collections import deque
 from typing import Callable, Sequence
 
 import jax
@@ -25,6 +45,10 @@ import numpy as np
 
 from ..core.heuristics import by_name
 from ..core.runtime import DTRRuntime, Operator
+
+# Ops whose outputs may still be computing when the host moves on (chosen on
+# one TPU v5e, PERF.md section 6).
+_MAX_INFLIGHT = 8
 
 
 class DTRArray:
@@ -110,7 +134,11 @@ class DTRContext:
         self.closures: dict[int, Callable] = {}     # op_id -> replay fn
         self.use_wallclock_cost = use_wallclock_cost
         self._pending_outputs: list[jax.Array] | None = None
+        self._costs: dict[tuple, float] = {}     # op signature -> seconds
+        self._inflight: deque[list] = deque()    # weakrefs to op outputs
         self.remat_runs = 0
+        self.timed_calls = 0       # calls that waited to time a new signature
+        self.inflight_waits = 0    # dispatches that waited on a full window
         # Optional repro.trace.TraceRecorder: mirrors every wrap/call/release
         # into a core.graph.Log (first executions only — rematerializations
         # are the runtime's own doing, not part of the operator stream).
@@ -141,6 +169,12 @@ class DTRContext:
 
         ``args`` may mix DTRArrays and plain arrays/scalars; plain values are
         captured in the closure (treated as op attributes, not tensors).
+
+        The op's cost is the time measured at the first call with its
+        signature in this context (name, input shapes and dtypes), or 1.0
+        without wall-clock costs.  Only that first call waits for the device;
+        otherwise the op is dispatched asynchronously and joins the in-flight
+        window (see the module docstring).
         """
         dtr_args = [a for a in args if isinstance(a, DTRArray)]
         in_tids = [a.tid for a in dtr_args]
@@ -151,13 +185,24 @@ class DTRContext:
             out = fn(*full)
             return out if isinstance(out, tuple) else (out,)
 
-        # Execute now with materialized inputs (also measures cost).
         concrete_in = [self.fetch(a) for a in dtr_args]
-        t0 = time.perf_counter()
-        outs = replay(*concrete_in)
-        jax.block_until_ready(outs)
-        elapsed = time.perf_counter() - t0
-        cost = max(elapsed, 1e-7) if self.use_wallclock_cost else 1.0
+        cost = 1.0
+        if self.use_wallclock_cost:
+            key = (name, *((a.shape, a.dtype) for a in dtr_args))
+            cost = self._costs.get(key)
+        if cost is None:
+            # A new signature: let queued work finish, then time the op alone.
+            jax.block_until_ready(([_live(e) for e in self._inflight],
+                                   concrete_in))
+            self._inflight.clear()
+            t0 = time.perf_counter()
+            outs = replay(*concrete_in)
+            jax.block_until_ready(outs)
+            cost = self._costs[key] = max(time.perf_counter() - t0, 1e-7)
+            self.timed_calls += 1
+        else:
+            outs = replay(*concrete_in)
+            self._dispatched(outs)
 
         self._pending_outputs = list(outs)
         oid = self.rt._next_oid
@@ -203,9 +248,27 @@ class DTRContext:
             self.remat_runs += 1
             ins = [self.buffers[tid] for tid in op.input_tids]
             outs = list(self.closures[op.op_id](*ins))
+            self._dispatched(outs)
         for tid, buf in zip(op.output_tids, outs):
             if self.rt.tensors[tid].defined:
                 self.buffers[tid] = buf
+
+    def _dispatched(self, outs) -> None:
+        """Add an op's outputs to the in-flight window; past
+        ``_MAX_INFLIGHT`` ops, wait for the oldest op whose outputs still
+        exist.  The device runs ops in the order they were dispatched, so
+        that op's being done implies the dropped ones before it are done."""
+        self._inflight.append([weakref.ref(o) for o in outs
+                               if isinstance(o, jax.Array)])
+        if len(self._inflight) <= _MAX_INFLIGHT:
+            return
+        while self._inflight:   # ``outs``, the newest entry, is live
+            live = _live(self._inflight.popleft())
+            if live:
+                if not all(o.is_ready() for o in live):
+                    self.inflight_waits += 1
+                    jax.block_until_ready(live)
+                return
 
     def _on_free(self, storage) -> None:
         for tid in storage.tensor_tids:
@@ -231,6 +294,11 @@ class DTRContext:
     def host_bytes(self) -> int:
         """Actual bytes currently parked in host copies."""
         return sum(int(b.nbytes) for b in self.host_buffers.values())
+
+
+def _live(refs) -> list:
+    """The outputs that an in-flight window entry's weakrefs still reach."""
+    return [o for o in (r() for r in refs) if o is not None]
 
 
 def op(ctx: DTRContext, name: str, fn: Callable) -> Callable:

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.eager import DTRContext, DTRArray, op
+from repro.eager import DTRContext, DTRArray, executor, op
 
 
 def test_basic_chain_correctness():
@@ -120,3 +120,130 @@ def test_training_loop_under_budget():
         w2 = ctx.call("sgd2", lambda w, g: w - lr * g, [w2, gw2])[0]
         losses.append(float(loss.value))
     assert losses[-1] < losses[0], f"no learning: {losses}"
+
+
+# ---------------------------------------------------------------------------
+# Costs per op signature and the bounded in-flight window
+# ---------------------------------------------------------------------------
+
+def test_same_signature_timed_once():
+    ctx = DTRContext(budget_bytes=float("inf"))
+    h = ctx.wrap(jnp.ones(256))
+    for _ in range(20):
+        h = ctx.call("scale", lambda a: a * 1.5, [h])[0]
+    assert ctx.timed_calls == 1
+    costs = {o.cost for o in ctx.rt.ops.values()}
+    assert len(costs) == 1 and costs.pop() > 0
+
+
+def test_distinct_names_shapes_and_dtypes_each_timed_once():
+    ctx = DTRContext(budget_bytes=float("inf"))
+    xs = [ctx.wrap(jnp.ones(8)), ctx.wrap(jnp.ones(16)),
+          ctx.wrap(jnp.ones(8, jnp.int32))]
+    for _ in range(3):
+        for x in xs:
+            ctx.call("neg", jnp.negative, [x])
+            ctx.call("abs", jnp.abs, [x])
+        ctx.call("add", jnp.add, [xs[0], xs[0]])
+        ctx.call("add", jnp.add, [xs[0], 2.0])   # one input: new signature
+    assert ctx.timed_calls == 2 * len(xs) + 2
+
+
+def test_unit_costs_never_sync_to_time(monkeypatch):
+    syncs = []
+    real = executor.jax.block_until_ready
+    monkeypatch.setattr(executor.jax, "block_until_ready",
+                        lambda x: syncs.append(1) or real(x))
+    ctx = DTRContext(budget_bytes=float("inf"), use_wallclock_cost=False)
+    h = ctx.wrap(jnp.ones(64))
+    for i in range(executor._MAX_INFLIGHT):
+        h = ctx.call(f"f{i}", lambda a: a * 1.5, [h])[0]
+    assert syncs == []                  # the window is not yet full
+    for i in range(30):
+        h = ctx.call(f"g{i}", lambda a: jnp.concatenate([a, a])[:64], [h])[0]
+    assert ctx.timed_calls == 0
+    assert len(syncs) == ctx.inflight_waits     # only the window waits
+    assert {o.cost for o in ctx.rt.ops.values()} == {1.0}
+    np.testing.assert_allclose(h.value, np.full(64, 1.5 ** 8), rtol=1e-6)
+
+
+@pytest.mark.parametrize("wallclock", [False, True])
+def test_inflight_window_bounded_after_calls_and_replays(wallclock):
+    n = 16 * 1024 // 4
+    ctx = DTRContext(budget_bytes=5 * 16 * 1024,
+                     use_wallclock_cost=wallclock)
+    vals = [ctx.wrap(jnp.linspace(0, 1, n))]
+    for i in range(24):
+        vals.append(ctx.call("step", lambda a: jnp.cos(a) * 1.01,
+                             [vals[-1]])[0])
+        assert len(ctx._inflight) <= executor._MAX_INFLIGHT
+    assert ctx.rt.evictions > 0
+    for i in (3, 11, 7):
+        before = ctx.remat_runs
+        ctx.fetch(vals[i])
+        assert ctx.remat_runs > before, "the fetch should replay"
+        assert len(ctx._inflight) <= executor._MAX_INFLIGHT
+    expect = np.linspace(0, 1, n)
+    for _ in range(7):
+        expect = np.cos(expect) * 1.01
+    np.testing.assert_allclose(np.asarray(vals[7].value), expect, rtol=1e-5)
+
+
+def test_first_runs_and_replays_join_the_window(monkeypatch):
+    monkeypatch.setattr(executor, "_MAX_INFLIGHT", 10 ** 6)
+    n = 16 * 1024 // 4
+    ctx = DTRContext(budget_bytes=5 * 16 * 1024, use_wallclock_cost=False)
+    vals = [ctx.wrap(jnp.linspace(0, 1, n))]
+    for i in range(24):
+        vals.append(ctx.call("step", lambda a: jnp.cos(a) * 1.01,
+                             [vals[-1]])[0])
+    ctx.fetch(vals[5])
+    assert ctx.remat_runs > 0
+    assert len(ctx._inflight) == 24 + ctx.remat_runs
+    assert ctx.inflight_waits == 0
+
+
+def test_window_keeps_no_dropped_buffer_alive():
+    ctx = DTRContext(budget_bytes=float("inf"), use_wallclock_cost=False)
+    h = ctx.call("scale", lambda a: a * 1.5, [ctx.wrap(jnp.ones(1024))])[0]
+    for _ in range(5):
+        nxt = ctx.call("scale", lambda a: a * 1.5, [h])[0]
+        h.release()
+        h = nxt
+    held = [o for refs in ctx._inflight for o in executor._live(refs)]
+    assert len(ctx._inflight) == 6
+    assert len(held) == 1 and held[0] is ctx.buffers[h.tid]
+
+
+def _mlp_grads(budget, wallclock):
+    """Weight gradients of a 6-layer tanh MLP, forward and manual backward
+    through DTR; returns (host gradients, context)."""
+    key = jax.random.PRNGKey(3)
+    ks = jax.random.split(key, 7)
+    n, d, layers = 64, 128, 6
+    ctx = DTRContext(budget_bytes=budget, use_wallclock_cost=wallclock)
+    ws = [ctx.wrap(jax.random.normal(ks[i], (d, d)) / d ** 0.5, name="w")
+          for i in range(layers)]
+    hs = [ctx.wrap(jax.random.normal(ks[-1], (n, d)), name="x")]
+    for w in ws:
+        hs.append(ctx.call("fc", lambda x, w_: jnp.tanh(x @ w_),
+                           [hs[-1], w])[0])
+    g = ctx.call("d_loss", lambda y: y / n, [hs[-1]])[0]
+    grads = []
+    for w, x, y in zip(reversed(ws), reversed(hs[:-1]), reversed(hs[1:])):
+        dz = ctx.call("d_tanh", lambda g_, y_: g_ * (1 - y_ * y_), [g, y])[0]
+        grads.append(ctx.call("d_w", lambda x_, d_: x_.T @ d_, [x, dz])[0])
+        g = ctx.call("d_x", lambda d_, w_: d_ @ w_.T, [dz, w])[0]
+    return [np.asarray(t.value) for t in grads], ctx
+
+
+@pytest.mark.parametrize("wallclock", [False, True])
+def test_budgeted_gradients_bit_identical_to_unbudgeted(wallclock):
+    free, _ = _mlp_grads(float("inf"), wallclock)
+    act = 64 * 128 * 4
+    pinned = 6 * 128 * 128 * 4 + act
+    got, ctx = _mlp_grads(pinned + 5 * act, wallclock)
+    assert ctx.rt.evictions > 0 and ctx.remat_runs > 0
+    assert ctx.timed_calls == (5 if wallclock else 0)   # one per op name
+    for a, b in zip(free, got):
+        assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
