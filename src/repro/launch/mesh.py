@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -20,7 +20,10 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        # Auto axes, as ``Mesh`` gives below: the model code places its
+        # tensors with sharding constraints, which explicit axes refuse.
+        return jax.make_mesh(shape, axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
     assert len(devices) >= n, (
         f"need {n} devices for mesh {shape}, have {len(devices)} — the "
         f"dry-run must set --xla_force_host_platform_device_count")

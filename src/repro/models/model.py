@@ -136,22 +136,26 @@ def block_apply(cfg: ModelConfig, kind: str, p, x, *, positions,
         h = L.rmsnorm_apply(cfg, p["norm1"], x)
         window = cfg.window if kind == "attn_local" else 0
         attn_cache = None if cache is None else cache.get("attn")
-        if cfg.mla:
-            a, c2 = MLA.mla_apply(cfg, p["attn"], h, positions=positions,
-                                  cache=attn_cache)
-        else:
-            a, c2 = L.attention_apply(cfg, p["attn"], h, positions=positions,
-                                      window=window, cache=attn_cache)
+        with jax.named_scope("attn"):
+            if cfg.mla:
+                a, c2 = MLA.mla_apply(cfg, p["attn"], h, positions=positions,
+                                      cache=attn_cache)
+            else:
+                a, c2 = L.attention_apply(cfg, p["attn"], h,
+                                          positions=positions, window=window,
+                                          cache=attn_cache)
         if c2 is not None:
             new_cache["attn"] = c2
         x = x + checkpoint_name(a, "attn_out")
         if kind == "cross":
             hc = L.rmsnorm_apply(cfg, p["norm_c"], x)
-            ca, _ = L.attention_apply(cfg, p["cross"], hc,
-                                      positions=positions, kv_x=img_kv)
+            with jax.named_scope("attn"):
+                ca, _ = L.attention_apply(cfg, p["cross"], hc,
+                                          positions=positions, kv_x=img_kv)
             x = x + checkpoint_name(ca, "cross_out")
         h2 = L.rmsnorm_apply(cfg, p["norm2"], x)
-        f = _ffn(cfg, p["ffn"], h2, moe_layer)
+        with jax.named_scope("ffn"):
+            f = _ffn(cfg, p["ffn"], h2, moe_layer)
         x = x + checkpoint_name(f, "ffn_out")
     elif kind == "rglru":
         h = L.rmsnorm_apply(cfg, p["norm1"], x)
@@ -300,24 +304,23 @@ def forward(cfg: ModelConfig, params, tokens, img_embed=None):
         tbody = _maybe_remat(cfg, group_body(cfg.tail, cfg.moe))
         x, _ = jax.lax.scan(tbody, x, params["tail"])
 
-    x = L.rmsnorm_apply(cfg, params["final_norm"], x)
-    return _unembed(cfg, params["embed"], x)
+    with jax.named_scope("lm_head"):
+        x = L.rmsnorm_apply(cfg, params["final_norm"], x)
+        return _unembed(cfg, params["embed"], x)
 
 
 def loss_fn(cfg: ModelConfig, params, batch):
     """Next-token cross entropy (fp32 logits for the softmax)."""
     tokens = batch["tokens"]
     logits = forward(cfg, params, tokens, batch.get("img_embed"))
-    logits = logits.astype(jnp.float32)
-    if cfg.n_codebooks > 0:
+    with jax.named_scope("lm_head"):
+        logits = logits.astype(jnp.float32)
         inp, tgt = logits[:, :-1], tokens[:, 1:]
         logp = jax.nn.log_softmax(inp, axis=-1)
         nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)
+        if cfg.n_codebooks == 0:
+            nll = nll[..., 0]
         return jnp.mean(nll)
-    inp, tgt = logits[:, :-1], tokens[:, 1:]
-    logp = jax.nn.log_softmax(inp, axis=-1)
-    nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-    return jnp.mean(nll)
 
 
 # ---------------------------------------------------------------------------
@@ -424,6 +427,7 @@ def decode_step(cfg: ModelConfig, params, token, cache, pos, img_embed=None):
         x, new_cache["tail"] = jax.lax.scan(
             tbody, x, (params["tail"], cache["tail"]))
 
-    x = L.rmsnorm_apply(cfg, params["final_norm"], x)
-    logits = _unembed(cfg, params["embed"], x)
+    with jax.named_scope("lm_head"):
+        x = L.rmsnorm_apply(cfg, params["final_norm"], x)
+        logits = _unembed(cfg, params["embed"], x)
     return logits, new_cache
