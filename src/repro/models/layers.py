@@ -7,13 +7,17 @@ attention, and single-token decode against a KV cache.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..distributed.sharding import ParamInfo, shard
+from ..distributed.sharding import ParamInfo, current_mesh, shard
+from ..kernels import flash_causal
 from .config import ModelConfig
 
 
@@ -119,8 +123,10 @@ def _sdpa_blocked(cfg: ModelConfig, q, k, v, window: int,
     """Flash-style blocked attention (XLA-level): scan over query blocks so
     the [Sq,Skv] logits never materialize — per-block peak is
     [B,KV,G,q_block,Skv].  Causal (+ sliding window) masking is computed per
-    block from positions.  The Pallas kernel (kernels/flash_attention.py) is
-    the TPU-tiled version of the same schedule."""
+    block from positions; blocks above the diagonal are masked, not
+    skipped.  Long causal self-attention takes ``_sdpa_flash`` when lowered
+    for a TPU; this serves every other long path (other platforms, sliding
+    windows, soft caps, MLA, meshes of more than one device)."""
     b, sq, h, d = q.shape
     dv = v.shape[-1]
     kvh = k.shape[2]
@@ -171,6 +177,60 @@ def _sdpa_blocked(cfg: ModelConfig, q, k, v, window: int,
 # Sequences at or above this length use the blocked attention path (tests
 # monkeypatch this down to cover the blocked path on CPU-sized inputs).
 BLOCKED_ATTN_THRESHOLD = 2048
+
+# Flash kernel tiles (query rows, key rows), from a sweep of 256/512/1024
+# on a v5e at the train cells' shapes; a tile never exceeds the sequence.
+FLASH_BLOCK_Q = 1024
+FLASH_BLOCK_KV = 1024
+
+
+def _sdpa_flash(cfg: ModelConfig, q, k, v):
+    """Causal self-attention through the Pallas TPU flash kernel
+    (kernels/flash_causal.py), forward and backward: scores and softmax
+    statistics stay in VMEM, tiles above the diagonal are skipped, and the
+    backward recomputes the probabilities from q, k and the saved
+    log-sum-exp.  Numerics as in ``_sdpa_blocked`` with f32 softmax.
+    q: [B,S,H,D]; k/v: [B,S,KV,D] -> [B,S,H,D]."""
+    def heads_major(x):
+        return jnp.swapaxes(x, 1, 2)
+    out = flash_causal.causal_flash_attention(
+        heads_major(q), heads_major(k), heads_major(v),
+        scale=1.0 / np.sqrt(cfg.head_dim), block_q=FLASH_BLOCK_Q,
+        block_k=FLASH_BLOCK_KV)
+    return heads_major(out)
+
+
+def _flash_engages(cfg: ModelConfig, q, window: int) -> bool:
+    """Long self-attention the kernel covers: causal with no window and no
+    soft cap, f32 softmax, shapes its tiles fit, and one device (the
+    kernel is not partitioned across a mesh)."""
+    mesh = current_mesh()
+    return (window == 0 and cfg.logit_softcap == 0 and cfg.softmax_f32
+            and (mesh is None or mesh.size == 1)
+            and flash_causal.fits(q.shape[1], q.shape[-1], FLASH_BLOCK_Q,
+                                  FLASH_BLOCK_KV))
+
+
+# Trace-time tally of the path each traced attention layer took: "kernel"
+# (``_sdpa_flash`` on a TPU lowering, ``_sdpa_blocked`` on any other),
+# "blocked" or "dense".  A layer scanned n times counts n (``layer_stack``).
+ATTN_PATHS: collections.Counter = collections.Counter()
+_STACK_DEPTH = [1]
+
+
+@contextlib.contextmanager
+def layer_stack(n: int):
+    """While tracing the body of a stack of ``n`` scanned layers, each
+    traced attention call counts ``n`` in ``ATTN_PATHS``."""
+    _STACK_DEPTH.append(_STACK_DEPTH[-1] * n)
+    try:
+        yield
+    finally:
+        _STACK_DEPTH.pop()
+
+
+def _tally(path: str) -> None:
+    ATTN_PATHS[path] += _STACK_DEPTH[-1]
 
 
 def causal_mask(sq: int, skv: int, window: int = 0) -> jax.Array:
@@ -227,7 +287,16 @@ def attention_apply(cfg: ModelConfig, p, x, *, positions, window: int = 0,
         mask = None
     elif cache is None:
         if x.shape[1] >= BLOCKED_ATTN_THRESHOLD:
-            out = _sdpa_blocked(cfg, q, k, v, window)
+            def blocked(q, k, v):
+                return _sdpa_blocked(cfg, q, k, v, window)
+            if _flash_engages(cfg, q, window):
+                _tally("kernel")
+                out = jax.lax.platform_dependent(
+                    q, k, v, tpu=functools.partial(_sdpa_flash, cfg),
+                    default=blocked)
+            else:
+                _tally("blocked")
+                out = blocked(q, k, v)
             dt_ = adtype(cfg)
             y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt_))
             return shard(y, "batch", "seq", "embed"), None
@@ -268,6 +337,7 @@ def attention_apply(cfg: ModelConfig, p, x, *, positions, window: int = 0,
         new_cache = {"k": k_all, "v": v_all, "pos": pos + 1}
         k, v = k_all, v_all
 
+    _tally("dense")
     out = _sdpa(cfg, q, k, v, mask)
     dt = adtype(cfg)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))

@@ -294,11 +294,13 @@ def forward(cfg: ModelConfig, params, tokens, img_embed=None):
             h, _ = block_apply(cfg, "attn", lp, carry, positions=positions,
                                moe_layer=False)
             return h, None
-        x, _ = jax.lax.scan(_maybe_remat(cfg, dense_body), x,
-                            params["dense"])
+        with L.layer_stack(cfg.n_dense_layers):
+            x, _ = jax.lax.scan(_maybe_remat(cfg, dense_body), x,
+                                params["dense"])
 
     body = _maybe_remat(cfg, group_body(cfg.pattern, cfg.moe))
-    x, _ = jax.lax.scan(body, x, params["groups"])
+    with L.layer_stack(cfg.n_groups):
+        x, _ = jax.lax.scan(body, x, params["groups"])
 
     if cfg.tail:
         tbody = _maybe_remat(cfg, group_body(cfg.tail, cfg.moe))
@@ -415,12 +417,14 @@ def decode_step(cfg: ModelConfig, params, token, cache, pos, img_embed=None):
             nc = {k2: {kk: vv for kk, vv in v2.items() if kk != "pos"}
                   for k2, v2 in (nc or {}).items()}
             return h, nc
-        x, new_cache["dense"] = jax.lax.scan(
-            dense_body, x, (params["dense"], cache["dense"]))
+        with L.layer_stack(cfg.n_dense_layers):
+            x, new_cache["dense"] = jax.lax.scan(
+                dense_body, x, (params["dense"], cache["dense"]))
 
     body = group_scan(cfg.pattern, params["groups"], cache["groups"], cfg.moe)
-    x, new_cache["groups"] = jax.lax.scan(
-        body, x, (params["groups"], cache["groups"]))
+    with L.layer_stack(cfg.n_groups):
+        x, new_cache["groups"] = jax.lax.scan(
+            body, x, (params["groups"], cache["groups"]))
 
     if cfg.tail:
         tbody = group_scan(cfg.tail, params["tail"], cache["tail"], cfg.moe)

@@ -24,10 +24,12 @@ from repro.kernels.moe_gemm import moe_grouped_gemm
 from repro.kernels.rwkv6_chunk import rwkv6_chunk
 from repro.launch.steps import (make_serve_step, make_train_step,
                                 state_shardings)
+from repro.models import layers as L
 from repro.models import model as M
 from repro.optim import adamw
 
 HBM_BYTES = 16 * 2**30          # one v5e chip
+TRAIN_JOB_BYTES = 15.75 * 2**30  # train cells: benchmarks/tpu/jobs
 
 
 @pytest.fixture(scope="module")
@@ -101,14 +103,14 @@ def test_kernel_compiles_at_model_width(one_chip, name):
 # The full-width steps chip_smoke.py runs
 # ---------------------------------------------------------------------------
 
-def _train_step_args(cfg, batch, placement):
+def _train_step_args(cfg, batch, placement, seq=cs.TRAIN_SEQ):
     """(params, opt_state, batch, max_loss) structs for ``make_train_step``
     with AdamW, as ``launch/train.py`` builds them."""
     opt = adamw(lr=3e-4)
     params = M.param_structs(cfg)
     opt_state = jax.eval_shape(opt.init, params)
     p_sh, o_sh, b_sh, s_sh = placement(opt)
-    tokens = jax.ShapeDtypeStruct((batch, cs.TRAIN_SEQ), jnp.int32)
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
     return opt, (_structs(params, p_sh), _structs(opt_state, o_sh),
                  _structs({"tokens": tokens}, b_sh),
                  jax.ShapeDtypeStruct((), jnp.float32, sharding=s_sh))
@@ -121,6 +123,28 @@ def test_train_step_fits_one_chip(one_chip):
     step = jax.jit(make_train_step(cfg, opt), donate_argnums=(0, 1))
     compiled = step.lower(*args).compile()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("arch,batch,seq", [("smollm-135m", 16, 2048),
+                                            ("qwen2-0.5b", 1, 4096)])
+def test_train_cell_step_takes_the_attention_kernel(one_chip, arch, batch,
+                                                    seq):
+    """The train cells' full-width steps: every layer's attention lowers to
+    the flash kernel, forward and backward, and the step fits the cells'
+    HBM budget."""
+    cfg = configs.get(arch).replace(remat="dtr")
+    opt, args = _train_step_args(cfg, batch, lambda opt: (one_chip,) * 4,
+                                 seq=seq)
+    L.ATTN_PATHS.clear()
+    step = jax.jit(make_train_step(cfg, opt), donate_argnums=(0, 1))
+    compiled = step.lower(*args).compile()
+    assert dict(L.ATTN_PATHS) == {"kernel": cfg.n_layers}
+    hlo = compiled.as_text()
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert any("flash_causal_fwd" in line for line in kernels)
+    assert any("flash_causal_bwd" in line for line in kernels)
+    assert _device_bytes(compiled) < TRAIN_JOB_BYTES
 
 
 def test_train_step_fits_four_chips_fsdp(topo):
