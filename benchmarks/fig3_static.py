@@ -1,7 +1,7 @@
 """Fig. 3 reproduction: DTR vs static checkpointing planners.
 
 On linear chains (where optimal static planning is tractable in closed form /
-DP — Checkmate's ILP solver is unavailable offline, noted in EXPERIMENTS.md),
+DP — Checkmate's ILP solver is not a dependency of this package),
 compares total executed forward ops:
 
   dtr_*        — online, no advance knowledge (h_dtr, h_dtr_eq, h_lru)

@@ -193,19 +193,6 @@ def perf_faults():
     return _timed("perf_faults", lambda: [m.run(smoke=True)], derive)
 
 
-def roofline():
-    from . import roofline as m
-
-    def derive(rows):
-        if not rows:
-            return "no dryrun artifacts (run repro.launch.dryrun --all)"
-        best = max(rows, key=lambda r: r["roofline_frac"])
-        return (f"cells={len(rows)} best={best['arch']}/{best['shape']}"
-                f"@{best['roofline_frac']}")
-
-    return _timed("roofline", m.load, derive)
-
-
 def main() -> None:
     print("name,us_per_call,derived")
     fig2_heuristics()
@@ -219,7 +206,6 @@ def main() -> None:
     perf_offload()
     perf_static()
     perf_faults()
-    roofline()
 
 
 if __name__ == "__main__":

@@ -3,8 +3,8 @@
 Serves a smoke-sized LM: requests arrive with prompts, get batched, prefilled
 (full forward populates nothing here — decode replays the prompt token by
 token to fill the cache, which is exact for these lengths), then decoded
-greedily for N tokens per request.  The serve step is the same function the
-dry-run lowers at decode_32k/long_500k scale.
+greedily for N tokens per request.  The serve step is
+``launch.steps.make_serve_step``, the one ``launch/serve.py`` runs.
 
   PYTHONPATH=src python examples/serve.py
 """

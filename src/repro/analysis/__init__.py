@@ -1,6 +1,4 @@
-"""Compiled-artifact analysis: HLO collective accounting + roofline terms."""
-from .hlo import collective_bytes, parse_collectives, xla_cost_dict
-from .roofline import RooflineTerms, roofline
+"""Compiled-artifact analysis: loop-aware HLO cost and XLA's cost dict."""
+from .hlo import xla_cost_dict
 
-__all__ = ["collective_bytes", "parse_collectives", "xla_cost_dict",
-           "RooflineTerms", "roofline"]
+__all__ = ["xla_cost_dict"]

@@ -2,10 +2,9 @@
 
 ``compiled.cost_analysis()`` counts every computation ONCE — a while loop
 body (layer scan, grad-accum loop) with known_trip_count=N is undercounted
-N×, which breaks roofline math for scanned layer stacks.  This module parses
-the HLO module, builds the call graph (fusion calls, while bodies with
-``known_trip_count``, conditionals), and rolls up per-instruction costs with
-loop multipliers:
+N×.  This module parses the HLO module, builds the call graph (fusion
+calls, while bodies with ``known_trip_count``, conditionals), and rolls up
+per-instruction costs with loop multipliers:
 
   flops   — dot ops: 2·|result|·|contracted|; elementwise: |result|
             (counted inside fusion computations too);
@@ -80,8 +79,6 @@ class Computation:
     name: str
     instrs: list = field(default_factory=list)
     is_fusion: bool = False
-    defs: dict = field(default_factory=dict)      # instr name -> opcode
-    sym: dict = field(default_factory=dict)       # instr name -> result type
     # parameter index -> effective bytes when the parameter is consumed only
     # through a slicing op inside this computation (the scan-over-stacked-
     # params pattern: a [L, ...] operand is read one slice per iteration).
@@ -95,11 +92,6 @@ class HloCost:
     bytes_accessed: float = 0.0
     collective_bytes: float = 0.0
     coll_by_kind: dict = field(default_factory=dict)
-    transcendentals: float = 0.0
-
-    def as_cost_dict(self) -> dict:
-        return {"flops": self.flops, "bytes accessed": self.bytes_accessed,
-                "transcendentals": self.transcendentals}
 
 
 def parse_module(text: str) -> tuple[dict[str, Computation], str]:
@@ -202,8 +194,6 @@ def parse_module(text: str) -> tuple[dict[str, Computation], str]:
                 ins.coll_bytes = b
                 ins.coll_kind = kind
                 break
-        cur.defs[name] = opcode
-        cur.sym[name] = rtype
         cur.instrs.append(ins)
 
     # Effective parameter bytes for fusion computations (slice-only use).
@@ -228,13 +218,10 @@ def parse_module(text: str) -> tuple[dict[str, Computation], str]:
     return comps, entry
 
 
-def analyze(text: str, flash_tile_threshold: float | None = None
-            ) -> HloCost:
-    """``flash_tile_threshold``: if set, instructions in loop nests with
-    multiplier > threshold count HBM bytes only for dot ops — modelling a
-    Pallas flash-attention kernel whose softmax intermediates stay in VMEM
-    (the threshold is the layer-scan multiplier; anything hotter is the
-    blocked-attention inner loop).  Labeled "analytic" in §Perf."""
+def analyze(text: str) -> HloCost:
+    """Per-device flops, HBM bytes and collective bytes by kind of an
+    optimized HLO module, each scaled by the trip counts of the loops it
+    runs in."""
     comps, entry = parse_module(text)
     cost = HloCost()
     if entry is None:
@@ -255,39 +242,12 @@ def analyze(text: str, flash_tile_threshold: float | None = None
                         b = rb + sum(
                             fc.param_eff.get(i, 0)
                             for i in range(len(ins.operands)))
-                if (flash_tile_threshold is not None
-                        and mult > flash_tile_threshold):
-                    # Analytic Pallas-kernel HBM model: only tensors that
-                    # cross the kernel boundary are charged.  Dots stream
-                    # externally-produced operands (q/k/v tiles); results
-                    # and in-body intermediates (logits/probs) stay VMEM.
-                    if ins.opcode == "dot":
-                        b = 0
-                        ext = ("parameter", "get-tuple-element",
-                               "dynamic-slice", "bitcast", "copy",
-                               "transpose", "reshape", "convert")
-                        for nm in ins.operands:
-                            if comp.defs.get(nm, "parameter") in ext:
-                                b += _shape_elems_bytes(
-                                    comp.sym.get(nm, ""))[1]
-                    elif "dynamic-update-slice" in ins.name:
-                        # o-tile write-back: smallest operand approximates
-                        # the update slice.
-                        obs = [_shape_elems_bytes(comp.sym.get(nm, ""))[1]
-                               for nm in ins.operands
-                               if comp.sym.get(nm)]
-                        b = 2 * min(obs) if obs else 0
-                    else:
-                        b = 0
                 cost.bytes_accessed += b * mult
             if ins.coll_kind:
                 cost.collective_bytes += ins.coll_bytes * mult
                 cost.coll_by_kind[ins.coll_kind] = (
                     cost.coll_by_kind.get(ins.coll_kind, 0)
                     + ins.coll_bytes * mult)
-            if ins.opcode in ("exponential", "tanh", "logistic", "log",
-                              "power", "erf"):
-                cost.transcendentals += ins.flops * mult
             child_mult = mult * (ins.trip if ins.opcode == "while" else 1)
             for c in ins.called:
                 visit(c, child_mult, depth + 1)

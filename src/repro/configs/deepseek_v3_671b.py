@@ -1,8 +1,9 @@
 """DeepSeek-V3-671B [arXiv:2412.19437]: MLA + 256-expert MoE top-8 + shared.
 
 61 layers: 3 leading dense-FFN layers + 58 MoE layers.  MLA dims per the
-paper: q_lora 1536, kv_lora 512, qk_nope 128, qk_rope 64, v 128.  MTP head
-omitted (noted in DESIGN.md §Arch-applicability).
+paper: q_lora 1536, kv_lora 512, qk_nope 128, qk_rope 64, v 128.  The
+multi-token-prediction (MTP) head is omitted: it is a training-objective
+add-on, not part of the layer stack that remat acts on.
 """
 from ..models.config import ModelConfig
 

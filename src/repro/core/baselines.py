@@ -1,8 +1,8 @@
 """Static checkpointing baselines for the Fig. 3 comparison.
 
 All baselines plan offline for a *linear chain* of N unit ops (the setting
-where optimal static planning is tractable without an ILP solver, which is
-unavailable offline in this container — noted in EXPERIMENTS.md):
+where optimal static planning is tractable without an ILP solver; no ILP
+solver is a dependency of this package):
 
   * ``chen_sqrt``   — Chen et al. (2016) √N segmentation: recompute each
                       segment once during backward.

@@ -12,12 +12,14 @@ Checkmate's ILP).  Pipeline:
      activation-byte budget; tensors tagged via
      ``jax.ad_checkpoint.checkpoint_name`` that were *never evicted* form the
      save-set.
-  3. ``policy_from_plan``: the save-set becomes
+  3. ``Plan.policy``: the save-set becomes
      ``jax.checkpoint_policies.save_only_these_names(...)``, enforced by XLA
-     remat — the runtime never sees the evicted activations at all.
+     remat — the runtime never sees the evicted activations at all
+     (``dtr_checkpoint`` wraps a function in it).
 
-Also provides ``block_remat``: DTR-planned segment checkpointing over scanned
-layer stacks (the √N pattern of Thm 3.1 emerges as the planned block size).
+Also provides ``plan_layer_blocks``: the remat block size for a scanned
+layer stack under a byte budget (the √N pattern of Thm 3.1 emerges as the
+planned block size).
 """
 from __future__ import annotations
 

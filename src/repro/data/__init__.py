@@ -1,4 +1,4 @@
 """Data pipeline: deterministic synthetic token streams, shard-aware."""
-from .pipeline import SyntheticLM, make_batch_specs
+from .pipeline import SyntheticLM
 
-__all__ = ["SyntheticLM", "make_batch_specs"]
+__all__ = ["SyntheticLM"]

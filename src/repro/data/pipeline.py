@@ -16,7 +16,6 @@ import threading
 from dataclasses import dataclass
 from typing import Iterator, Optional
 
-import jax
 import numpy as np
 
 
@@ -85,18 +84,3 @@ class Prefetcher:
     def stop(self):
         self._stop.set()
 
-
-def make_batch_specs(cfg, batch: int, seq_len: int,
-                     dtype=np.int32) -> dict:
-    """ShapeDtypeStruct batch stand-ins for lowering (dry-run input_specs)."""
-    if cfg.n_codebooks:
-        tok = jax.ShapeDtypeStruct((batch, seq_len, cfg.n_codebooks),
-                                   np.dtype(dtype))
-    else:
-        tok = jax.ShapeDtypeStruct((batch, seq_len), np.dtype(dtype))
-    specs = {"tokens": tok}
-    if cfg.cross_attn_dim:
-        specs["img_embed"] = jax.ShapeDtypeStruct(
-            (batch, cfg.cross_attn_tokens, cfg.cross_attn_dim),
-            np.dtype("bfloat16"))
-    return specs

@@ -2,21 +2,15 @@
 
 ``compressed_psum``: int8-quantized gradient all-reduce — per-chunk scale
 quantization, integer psum, dequantize.  Cuts DP all-reduce bytes 4× vs f32
-(2× vs bf16) at ~1e-2 relative error; opt-in per train-step config.  Runs
-under ``shard_map`` over the data axes; exact-dtype fallback otherwise.
-
-``reduce_scatter_grads`` / ``all_gather_params``: explicit ZeRO-1 decomposed
-collectives for overlap experiments (§Perf): XLA can schedule the
-reduce-scatter of step N's grads against step N+1's forward all-gathers.
+(2× vs bf16) at ~1e-2 relative error.  Runs under ``shard_map`` over the
+data axes; ``make_compressed_allreduce`` wraps it for use outside one.
 """
 from __future__ import annotations
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
 
 def quantize_int8(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -75,12 +69,3 @@ def make_compressed_allreduce(mesh: Mesh, data_axes: tuple[str, ...]):
 
     return fn
 
-
-# ---------------------------------------------------------------------------
-# Explicit DP decomposition (overlap material for §Perf)
-# ---------------------------------------------------------------------------
-
-def psum_grads(grads, mesh: Mesh, data_axes=("pod", "data")):
-    """Plain (exact) DP grad mean via sharding constraint — lets XLA choose
-    all-reduce vs reduce-scatter+all-gather under SPMD."""
-    return grads  # SPMD inserts the reduction from out_shardings; hook point.

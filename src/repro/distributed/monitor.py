@@ -1,4 +1,4 @@
-"""Straggler / health monitoring for long-running multi-pod jobs.
+"""Straggler / health monitoring for long-running training jobs.
 
 No real cluster exists in this container, so this is the framework layer a
 deployment would wire to its scheduler: per-step wall-time EWMA + outlier
@@ -30,7 +30,7 @@ class StragglerMonitor:
 
     A step slower than ``threshold``× the EWMA is flagged (straggling host /
     preemption precursor / input stall).  ``patience`` consecutive flags fire
-    ``on_straggler`` (deployments: exclude pod, re-shard, checkpoint)."""
+    ``on_straggler`` (deployments: exclude host, re-shard, checkpoint)."""
     alpha: float = 0.1
     threshold: float = 2.0
     patience: int = 3

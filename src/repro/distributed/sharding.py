@@ -1,12 +1,11 @@
-"""Logical-axis sharding rules (MaxText-style) for the production mesh.
+"""Logical-axis sharding rules (MaxText-style) for a device mesh.
 
 Model code annotates tensors with *logical* axes (``shard(x, "batch", None,
 "embed")``); a single rules table maps logical axes to physical mesh axes.
 Flipping parallelism strategy (pure DP, TP, FSDP, SP, EP) touches only this
 table / per-run overrides — never model code.
 
-Physical mesh axes: ``("pod", "data", "model")`` multi-pod or
-``("data", "model")`` single-pod (launch/mesh.py).
+Physical mesh axes: ``("data", "model")`` (launch/mesh.make_host_mesh).
 """
 from __future__ import annotations
 
@@ -22,7 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # Logical axis -> physical mesh axis (or tuple of axes, or None=replicated).
 LOGICAL_RULES: dict[str, object] = {
-    "batch": ("pod", "data"),   # data parallel over pod x data
+    "batch": "data",            # data parallel
     "seq": None,                # sequence replicated by default (SP flips this)
     "seq_model": "model",       # explicit sequence-parallel annotation
     "embed": None,              # activation d_model dim replicated
@@ -31,9 +30,9 @@ LOGICAL_RULES: dict[str, object] = {
     "mlp": "model",             # TP over FFN hidden
     "vocab": "model",           # TP over vocab (embedding + logits)
     "expert": "model",          # EP: experts over model axis
-    "expert_cap": ("pod", "data"),  # expert capacity dim over data
+    "expert_cap": "data",       # expert capacity dim over data
     "kv_seq": None,             # KV-cache sequence dim
-    "fsdp": ("pod", "data"),    # param dim additionally sharded when FSDP on
+    "fsdp": "data",             # param dim additionally sharded when FSDP on
     "lru": "model",             # RG-LRU width
     "conv": None,
 }
@@ -190,7 +189,8 @@ def axis_resources(tree, mesh: Optional[Mesh] = None, fsdp: bool = False):
 
 
 def shape_structs(tree):
-    """ParamInfo tree -> ShapeDtypeStruct tree (for dry-run lowering)."""
+    """ParamInfo tree -> ShapeDtypeStruct tree (lowering without
+    allocation)."""
     def one(info: ParamInfo):
         return jax.ShapeDtypeStruct(info.shape, np.dtype(info.dtype))
 

@@ -1,1 +1,1 @@
-"""Launch layer: production mesh, train/serve steps, dry-run, drivers."""
+"""Launch layer: host mesh, train/serve steps, drivers."""

@@ -1,9 +1,8 @@
-"""Production serving driver: continuous-batching decode loop.
+"""Serving driver: continuous-batching decode loop on a host mesh.
 
 A request queue feeds a fixed-width decode batch; finished slots are
 immediately refilled from the queue (continuous batching).  The step function
-is the same `make_serve_step` the dry-run lowers at decode_32k / long_500k
-scale; on hardware the mesh flag drives the full slice.
+is ``launch.steps.make_serve_step``, run over this host's devices.
 
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b --smoke \
       --requests 12 --slots 4 --gen 16
@@ -27,7 +26,7 @@ import numpy as np
 from repro import configs
 from repro.distributed.sharding import mesh_context
 from repro.launch.compile_cache import use_compile_cache
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_serve_step
 from repro.models import model as M
 
@@ -50,8 +49,6 @@ def main(argv=None) -> ServeSummary:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--mesh", default="host",
-                    choices=["host", "production", "multipod"])
     ap.add_argument("--slots", type=int, default=4,
                     help="decode batch width (continuous batching slots)")
     ap.add_argument("--requests", type=int, default=12)
@@ -114,10 +111,7 @@ def main(argv=None) -> ServeSummary:
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get(args.arch))
-    mesh = {"host": make_host_mesh,
-            "production": make_production_mesh,
-            "multipod": lambda: make_production_mesh(multi_pod=True)}[
-        args.mesh]()
+    mesh = make_host_mesh()
 
     rng = np.random.default_rng(0)
     queue = deque(

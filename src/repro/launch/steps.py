@@ -169,17 +169,6 @@ def state_shardings(cfg: ModelConfig, mesh: Mesh, opt_name: str,
     return p_sh, OptState(step=scalar, inner=o_sh)
 
 
-def opt_state_structs(cfg: ModelConfig, opt_name: str):
-    """ShapeDtypeStruct tree of the optimizer state (dry-run input)."""
-    defs = M.param_defs(cfg)
-    infos = _opt_state_infos(opt_name, defs, zero1=True)
-    structs = jax.tree.map(
-        lambda i: jax.ShapeDtypeStruct(i.shape, np.dtype(i.dtype)),
-        infos, is_leaf=lambda x: isinstance(x, ParamInfo))
-    return OptState(step=jax.ShapeDtypeStruct((), np.dtype("int32")),
-                    inner=structs)
-
-
 def batch_shardings(cfg: ModelConfig, mesh: Mesh, specs: dict):
     def of(struct):
         ndim = len(struct.shape)
@@ -187,9 +176,3 @@ def batch_shardings(cfg: ModelConfig, mesh: Mesh, specs: dict):
         return NamedSharding(mesh, pspec(*axes, mesh=mesh))
     return jax.tree.map(of, specs)
 
-
-def cache_shardings(cfg: ModelConfig, mesh: Mesh, batch: int, max_len: int):
-    defs = M.cache_defs(cfg, batch, max_len)
-    return jax.tree.map(
-        lambda i: NamedSharding(mesh, param_pspec(i, mesh=mesh, fsdp=False)),
-        defs, is_leaf=lambda x: isinstance(x, ParamInfo))

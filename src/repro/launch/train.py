@@ -1,18 +1,16 @@
-"""Production training driver.
+"""Training driver.
 
-Config-driven: pick an arch, a mesh (production 16×16 / 2×16×16 or a host
-mesh for local runs), parallelism knobs (fsdp, seq sharding, grad accum,
-remat policy) and run a fault-tolerant training loop: sharded train state,
-deterministic seekable data, periodic atomic checkpoints, NaN/divergence
-guard with restore, straggler monitoring.
+Config-driven: pick an arch, parallelism knobs over this host's devices
+(fsdp, grad accum, remat policy) and run a fault-tolerant
+training loop: sharded train state, deterministic seekable data, periodic
+atomic checkpoints, NaN/divergence guard with restore, straggler monitoring.
 
   # local smoke (CPU, host mesh):
   PYTHONPATH=src python -m repro.launch.train --arch llama3.2-1b --smoke \
       --steps 50
-  # production lowering check without hardware is the dry-run; on a real
-  # slice the same flags drive the full mesh:
-  PYTHONPATH=src python -m repro.launch.train --arch mixtral-8x7b \
-      --mesh production --batch 256 --seq 4096 --fsdp --remat dtr
+  # FSDP over the four chips of one host:
+  PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
+      --batch 32 --seq 1024 --devices 4 --fsdp --remat dtr
 """
 from __future__ import annotations
 
@@ -32,7 +30,7 @@ from repro.distributed.monitor import (DivergenceGuard, MemoryMonitor,
                                        StragglerMonitor, Timer)
 from repro.distributed.sharding import mesh_context
 from repro.launch.compile_cache import use_compile_cache
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import (batch_shardings, make_train_step,
                                 state_shardings)
 from repro.models import model as M
@@ -67,8 +65,6 @@ def main(argv=None) -> TrainSummary:
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (CPU-sized)")
-    ap.add_argument("--mesh", default="host",
-                    choices=["host", "production", "multipod"])
     ap.add_argument("--devices", type=int, default=0,
                     help="host mesh over the first N devices (0 = all)")
     ap.add_argument("--steps", type=int, default=100)
@@ -77,8 +73,6 @@ def main(argv=None) -> TrainSummary:
     ap.add_argument("--grad-accum", type=int, default=1)
     ap.add_argument("--remat", default="dtr")
     ap.add_argument("--fsdp", action="store_true")
-    ap.add_argument("--seq-shard", action="store_true",
-                    help="Megatron-style sequence sharding (seq->model)")
     ap.add_argument("--optimizer", default="adamw",
                     choices=["adamw", "adafactor"])
     ap.add_argument("--lr", type=float, default=3e-4)
@@ -92,18 +86,14 @@ def main(argv=None) -> TrainSummary:
     if args.smoke:
         cfg = cfg.replace(dtype="float32")
 
-    mesh = {"host": lambda: make_host_mesh(n_devices=args.devices),
-            "production": make_production_mesh,
-            "multipod": lambda: make_production_mesh(multi_pod=True)}[
-        args.mesh]()
-    overrides = {"seq": "model"} if args.seq_shard else {}
+    mesh = make_host_mesh(n_devices=args.devices)
 
     opt = (adafactor(lr=args.lr) if args.optimizer == "adafactor"
            else adamw(lr=cosine_schedule(args.lr, warmup=20,
                                          total=args.steps)))
     summary = TrainSummary()
 
-    with mesh_context(mesh, overrides=overrides, fsdp=args.fsdp):
+    with mesh_context(mesh, fsdp=args.fsdp):
         key = jax.random.PRNGKey(0)
         p_sh, o_sh = state_shardings(cfg, mesh, opt.name, fsdp=args.fsdp)
         # Initialized in place, shard by shard: built eagerly, the whole

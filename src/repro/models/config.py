@@ -79,15 +79,13 @@ class ModelConfig:
     cross_attn_dim: int = 0              # vlm: vision embedding dim
 
     # Numerics / training
-    softmax_f32: bool = True        # f32 attention logits (bf16 = perf knob)
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
 
-    # Remat (the paper's technique): policy selected by the DTR planner.
-    remat: str = "none"                  # none|dtr|full|names
-    remat_budget_frac: float = 0.5       # fraction of per-device HBM for acts
+    # Remat (the paper's technique): the scan body's jax.checkpoint policy.
+    remat: str = "none"                  # none|dtr|full|names:<a>,<b>
 
     def __post_init__(self):
         if self.head_dim == 0:
@@ -106,7 +104,7 @@ class ModelConfig:
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
-    # -- parameter counting (for roofline MODEL_FLOPS) ---------------------
+    # -- parameter counting ------------------------------------------------
     def param_count(self) -> int:
         d, f, v = self.d_model, self.d_ff, self.vocab
         h, kv, hd = self.n_heads, self.n_kv_heads, self.head_dim
@@ -151,16 +149,5 @@ class ModelConfig:
                 else:
                     total += 3 * d * f
         # deepseek: leading dense layers use d_ff, already counted via moe
-        # approximation; close enough for roofline purposes.
+        # approximation.
         return int(total)
-
-    def active_param_count(self) -> int:
-        """Active params per token (MoE: top_k + shared experts only)."""
-        if not self.moe:
-            return self.param_count()
-        d = self.d_model
-        full = self.param_count()
-        moe_layers = self.n_layers - self.n_dense_layers
-        all_expert = moe_layers * self.n_experts * 3 * d * self.moe_d_ff
-        active_expert = moe_layers * self.top_k * 3 * d * self.moe_d_ff
-        return int(full - all_expert + active_expert)

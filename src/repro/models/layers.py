@@ -137,20 +137,18 @@ def _sdpa_blocked(cfg: ModelConfig, q, k, v, window: int,
     assert sq % q_block == 0, (sq, q_block)
     qb = q.reshape(b, nb, q_block, h, d).transpose(1, 0, 2, 3, 4)
     # Pin layouts across the scan so XLA does not re-shard k/v (or the qb
-    # slices) on every q-block iteration — the in-loop all-to-alls dominate
-    # the collective term otherwise (EXPERIMENTS.md §Perf, llama cell).
+    # slices) on every q-block iteration: without the pins, in-loop
+    # all-to-alls move k and v once per query block.
     qb = shard(qb, None, "batch", None, "heads", None)
     k = shard(k, "batch", None, "kv_heads", None)
     v = shard(v, "batch", None, "kv_heads", None)
     kpos = jnp.arange(k.shape[1])
 
-    acc_dt = jnp.float32 if cfg.softmax_f32 else jnp.bfloat16
-
     def body(carry, inp):
         qi, blk = inp
         qi = qi.reshape(b, q_block, kvh, g, d)
         logits = jnp.einsum("bqkgd,bskd->bkgqs", qi, k).astype(
-            acc_dt) * scale
+            jnp.float32) * scale
         if cfg.logit_softcap > 0:
             c = cfg.logit_softcap
             logits = c * jnp.tanh(logits / c)
@@ -159,8 +157,7 @@ def _sdpa_blocked(cfg: ModelConfig, q, k, v, window: int,
         if window > 0:
             m = m & (qpos[:, None] - kpos[None, :] < window)
         logits = jnp.where(m[None, None, None], logits,
-                           jnp.asarray(-3e4 if acc_dt == jnp.bfloat16
-                                       else -1e30, acc_dt))
+                           jnp.asarray(-1e30, jnp.float32))
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
         out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
         out = out.reshape(b, q_block, h, dv)
@@ -189,7 +186,7 @@ def _sdpa_flash(cfg: ModelConfig, q, k, v):
     (kernels/flash_causal.py), forward and backward: scores and softmax
     statistics stay in VMEM, tiles above the diagonal are skipped, and the
     backward recomputes the probabilities from q, k and the saved
-    log-sum-exp.  Numerics as in ``_sdpa_blocked`` with f32 softmax.
+    log-sum-exp.  Numerics as in ``_sdpa_blocked``: softmax in f32.
     q: [B,S,H,D]; k/v: [B,S,KV,D] -> [B,S,H,D]."""
     def heads_major(x):
         return jnp.swapaxes(x, 1, 2)
@@ -202,10 +199,10 @@ def _sdpa_flash(cfg: ModelConfig, q, k, v):
 
 def _flash_engages(cfg: ModelConfig, q, window: int) -> bool:
     """Long self-attention the kernel covers: causal with no window and no
-    soft cap, f32 softmax, shapes its tiles fit, and one device (the
-    kernel is not partitioned across a mesh)."""
+    soft cap, shapes its tiles fit, and one device (the kernel is not
+    partitioned across a mesh)."""
     mesh = current_mesh()
-    return (window == 0 and cfg.logit_softcap == 0 and cfg.softmax_f32
+    return (window == 0 and cfg.logit_softcap == 0
             and (mesh is None or mesh.size == 1)
             and flash_causal.fits(q.shape[1], q.shape[-1], FLASH_BLOCK_Q,
                                   FLASH_BLOCK_KV))

@@ -113,7 +113,7 @@ def init_params(cfg: ModelConfig, key) -> Any:
 
 
 def param_structs(cfg: ModelConfig):
-    """ShapeDtypeStruct tree (dry-run: no allocation)."""
+    """ShapeDtypeStruct tree (lowering without allocation)."""
     return shape_structs(param_defs(cfg))
 
 
@@ -191,12 +191,10 @@ def remat_policy(cfg: ModelConfig):
         return None
     if cfg.remat == "full":
         return jax.checkpoint_policies.nothing_saveable
-    if cfg.remat == "dots":
-        return jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
     if cfg.remat == "dtr":
-        # Planned offline via core.planner.plan_model_policy; default saves
-        # block outputs only (the residual-stream checkpoints DTR keeps on
-        # homogeneous stacks — see EXPERIMENTS.md §Perf for planned variants).
+        # A fixed save-set: the attention and FFN block outputs, the
+        # residual-stream checkpoints DTR keeps on homogeneous stacks.  No
+        # planner picks it yet (core.planner.plan is not on this path).
         return jax.checkpoint_policies.save_only_these_names(
             "attn_out", "ffn_out")
     if cfg.remat.startswith("names:"):
@@ -278,7 +276,7 @@ def forward(cfg: ModelConfig, params, tokens, img_embed=None):
             # Barrier: keep the per-layer FSDP all-gather INSIDE the scan
             # body — without it XLA commutes gather/slice and hoists the
             # full gathered param stack out of the loop (81 GiB resident
-            # for deepseek-v3; EXPERIMENTS.md §Perf cell B).
+            # for deepseek-v3).
             slot_params = _opt_barrier(slot_params)
             h = carry
             for i, kind in enumerate(kinds):

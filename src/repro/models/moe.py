@@ -29,7 +29,7 @@ def moe_defs(cfg: ModelConfig) -> dict:
         # FSDP dim: sharding the non-contracting dim instead was tried and
         # REFUTED — XLA materializes the fully-gathered expert stack
         # (333 GiB/device); the contracting-dim layout costs partial-sum
-        # all-reduces but stays 7x smaller (EXPERIMENTS.md §Perf cell B).
+        # all-reduces but stays 7x smaller.
         "wi": ParamInfo((e, d, f), cfg.param_dtype,
                         ("expert", None, "mlp"), fsdp_dim=1),
         "wg": ParamInfo((e, d, f), cfg.param_dtype,
@@ -93,7 +93,7 @@ def moe_apply(cfg: ModelConfig, p, x):
     # are vmapped over batch so the batch dim is a *scatter batch dim* —
     # 2D-indexed .at[bidx, slot] forms are unpartitionable and force XLA
     # SPMD to replicate the full dispatch buffer (30 GB/layer for
-    # deepseek-v3; see EXPERIMENTS.md §Perf cell B).
+    # deepseek-v3).
     gathered = jax.vmap(lambda xr, tr: xr[tr])(x, src_tok)
     gathered = gathered * keep[..., None].astype(dt)
     buf = jax.vmap(

@@ -8,10 +8,10 @@ state-update step.
 
 Faithful pieces: per-channel data-dependent decay w_t = exp(−exp(w0 + LoRA(x)))
 (Finch's core novelty), bonus ``u`` term, token-shift mixing, silu output
-gate, grouped head norm, squared-ReLU channel-mix.  Simplification recorded
-in DESIGN.md: token-shift mixing coefficients are learned-static (μ) rather
-than the paper's data-dependent ddlerp — the recurrence itself keeps full
-data dependence.
+gate, grouped head norm, squared-ReLU channel-mix.  One simplification:
+token-shift mixing coefficients are learned-static (μ) rather than the
+paper's data-dependent ddlerp — the recurrence itself keeps full data
+dependence.
 """
 from __future__ import annotations
 

@@ -1,12 +1,10 @@
-"""Tests for the HLO analyzers (collective parse + loop-aware cost)."""
+"""Tests for the loop-aware HLO cost analyzer."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.analysis.hlo import parse_collectives
 from repro.analysis.hlo_cost import analyze, parse_module
-from repro.analysis.roofline import RooflineTerms, roofline
 
 
 def _compile(fn, *args):
@@ -50,31 +48,80 @@ class TestHloCost:
         assert entry in comps
 
 
-class TestRoofline:
-    def test_terms_and_dominant(self):
-        rt = roofline({"flops": 197e12, "bytes accessed": 819e9},
-                      coll_bytes=0, chips=1, model_flops=197e12)
-        assert rt.compute_s == pytest.approx(1.0)
-        assert rt.memory_s == pytest.approx(1.0)
-        assert rt.dominant in ("compute", "memory")
-        assert rt.roofline_frac == pytest.approx(1.0)
-
-    def test_collective_dominates(self):
-        rt = roofline({"flops": 1e12, "bytes accessed": 1e9},
-                      coll_bytes=50e9 * 10, chips=4, model_flops=1e12)
-        assert rt.dominant == "collective"
-        assert rt.step_time_s == pytest.approx(10.0)
-
-
-class TestCollectiveParse:
-    def test_counts_and_bytes(self):
-        txt = """
-  %all-reduce.1 = f32[16,256]{1,0} all-reduce(%dot.1), channel_id=1
-  %all-gather.2 = bf16[32,64]{1,0} all-gather(%p), dimensions={0}
-  %all-gather-done.1 = bf16[32,64]{1,0} all-gather-done(%x)
+# Optimized HLO names a collective's operands without their shapes, so a
+# collective counts the bytes of its per-device result.
+_SUM = """%sum (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %add = f32[] add(%a, %b)
+}
 """
-        st = parse_collectives(txt)
-        assert st.count_by_kind["all-reduce"] == 1
-        assert st.count_by_kind["all-gather"] == 1
-        assert st.bytes_by_kind["all-reduce"] == 16 * 256 * 4
-        assert st.bytes_by_kind["all-gather"] == 32 * 64 * 2
+_GROUPS = "channel_id=1, replica_groups={{0,1,2,3}}"
+
+
+def _entry(param: str, *lines: str) -> str:
+    body = "\n".join("  " + ln for ln in lines)
+    return (f"HloModule m\n\n{_SUM}\n"
+            f"ENTRY %main (p0: {param}) -> f32[] {{\n"
+            f"  %p0 = {param}{{1,0}} parameter(0)\n{body}\n}}\n")
+
+
+_WHILE = _SUM + """
+%body (arg: (s32[], f32[8,128])) -> (s32[], f32[8,128]) {
+  %arg = (s32[], f32[8,128]{1,0}) parameter(0)
+  %i = s32[] get-tuple-element(%arg), index=0
+  %x = f32[8,128]{1,0} get-tuple-element(%arg), index=1
+  %ar = f32[8,128]{1,0} all-reduce(%x), """ + _GROUPS + """, to_apply=%sum
+  ROOT %t = (s32[], f32[8,128]{1,0}) tuple(%i, %ar)
+}
+
+%cond (arg: (s32[], f32[8,128])) -> pred[] {
+  %arg = (s32[], f32[8,128]{1,0}) parameter(0)
+  ROOT %go = pred[] constant(true)
+}
+
+ENTRY %main (p0: f32[8,128]) -> (s32[], f32[8,128]) {
+  %p0 = f32[8,128]{1,0} parameter(0)
+  %zero = s32[] constant(0)
+  %init = (s32[], f32[8,128]{1,0}) tuple(%zero, %p0)
+  ROOT %w = (s32[], f32[8,128]{1,0}) while(%init), condition=%cond, \
+body=%body, backend_config={"known_trip_count":{"n":"6"}}
+}
+"""
+
+_COLLECTIVE_CASES = {
+    "all-gather": (_entry(
+        "bf16[8,128]", "ROOT %ag = bf16[32,128]{1,0} all-gather(%p0), "
+        + _GROUPS + ", dimensions={0}"), {"all-gather": 32 * 128 * 2}),
+    "all-reduce": (_entry(
+        "f32[8,128]", "ROOT %ar = f32[8,128]{1,0} all-reduce(%p0), "
+        + _GROUPS + ", to_apply=%sum"), {"all-reduce": 8 * 128 * 4}),
+    "reduce-scatter": (_entry(
+        "f32[8,128]", "ROOT %rs = f32[2,128]{1,0} reduce-scatter(%p0), "
+        + _GROUPS + ", dimensions={0}, to_apply=%sum"),
+        {"reduce-scatter": 2 * 128 * 4}),
+    "all-to-all": (_entry(
+        "f32[8,128]", "ROOT %a2a = f32[8,128]{1,0} all-to-all(%p0), "
+        + _GROUPS + ", dimensions={0}"), {"all-to-all": 8 * 128 * 4}),
+    "collective-permute": (_entry(
+        "s32[8,128]", "ROOT %cp = s32[8,128]{1,0} collective-permute(%p0), "
+        "channel_id=1, source_target_pairs={{0,1},{1,2},{2,3},{3,0}}"),
+        {"collective-permute": 8 * 128 * 4}),
+    # The -start of an async pair carries the transfer; its -done does not.
+    "async-pair": (_entry(
+        "f32[8,128]",
+        "%ars = f32[8,128]{1,0} all-reduce-start(%p0), " + _GROUPS
+        + ", to_apply=%sum",
+        "ROOT %ard = f32[8,128]{1,0} all-reduce-done(%ars)"),
+        {"all-reduce": 8 * 128 * 4}),
+    # A collective in a while body runs once per trip.
+    "while-body": (_WHILE, {"all-reduce": 6 * 8 * 128 * 4}),
+}
+
+
+@pytest.mark.parametrize("case", list(_COLLECTIVE_CASES))
+def test_collective_bytes_by_kind(case):
+    text, want = _COLLECTIVE_CASES[case]
+    cost = analyze(text)
+    assert cost.coll_by_kind == want
+    assert cost.collective_bytes == sum(want.values())

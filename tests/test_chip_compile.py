@@ -1,5 +1,6 @@
-"""Compiles for a described TPU v5e: the Pallas kernels at model widths and
-the steps ``chip_smoke.py`` runs, at full size.  No chip is needed: the TPU
+"""Compiles for a described TPU v5e: the Pallas kernels at model widths, the
+train cells' steps, and the train and serve steps ``chip_smoke.py`` runs, at
+full size on one chip and FSDP over four.  No chip is needed: the TPU
 compiler is installed and compiles for a ``v5e:2x2`` topology that is only
 described, so what it refuses (tiling, VMEM, memory that does not fit the
 16 GiB of HBM) is caught here.  Nothing runs, so nothing here is a timing.
@@ -147,18 +148,21 @@ def test_train_cell_step_takes_the_attention_kernel(one_chip, arch, batch,
     assert _device_bytes(compiled) < TRAIN_JOB_BYTES
 
 
-def test_train_step_fits_four_chips_fsdp(topo):
-    """The ``--chips 4`` path: data=4 FSDP at 4x the one-chip batch.  Every
+@pytest.mark.parametrize("arch,batch,seq", [
+    ("smollm-135m", 4 * cs.TRAIN_BATCH, cs.TRAIN_SEQ),
+    ("qwen2-0.5b", 4, 4096)])      # the Qwen2 train cell's 1x4096 per chip
+def test_train_step_fits_four_chips_fsdp(topo, arch, batch, seq):
+    """The ``--chips 4`` path: data=4 FSDP at 4x a one-chip batch.  Every
     device holds a share and the gradients are reduced across devices."""
-    cfg = configs.get(cs.TRAIN_ARCH).replace(remat="dtr")
+    cfg = configs.get(arch).replace(remat="dtr")
     mesh = Mesh(np.asarray(topo.devices[:4]).reshape(4, 1), ("data", "model"))
 
     p_sh, o_sh = state_shardings(cfg, mesh, "adamw", fsdp=True)
     with mesh_context(mesh, fsdp=True):
         opt, args = _train_step_args(
-            cfg, 4 * cs.TRAIN_BATCH,
+            cfg, batch,
             lambda opt: (p_sh, o_sh, NamedSharding(mesh, P("data")),
-                         NamedSharding(mesh, P())))
+                         NamedSharding(mesh, P())), seq=seq)
         step = jax.jit(make_train_step(cfg, opt),
                        out_shardings=(p_sh, o_sh, None),
                        donate_argnums=(0, 1))
@@ -168,8 +172,9 @@ def test_train_step_fits_four_chips_fsdp(topo):
     assert "all-reduce" in hlo or "reduce-scatter" in hlo
 
 
-def test_serve_step_fits_one_chip(one_chip):
-    cfg = configs.get(cs.SERVE_ARCH)
+@pytest.mark.parametrize("arch", ["smollm-135m", "qwen2-0.5b"])
+def test_serve_step_fits_one_chip(one_chip, arch):
+    cfg = configs.get(arch)
     params = _structs(M.param_structs(cfg), one_chip)
     cache = _structs(M.cache_structs(cfg, cs.SERVE_SLOTS, cs.SERVE_MAX_LEN),
                      one_chip)

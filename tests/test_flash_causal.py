@@ -153,8 +153,8 @@ def test_full_size_train_steps_take_the_kernel_in_every_layer():
         assert dict(L.ATTN_PATHS) == {"kernel": cfg.n_layers}, arch
 
 
-@pytest.mark.parametrize("case", ["window", "softcap", "bf16_softmax",
-                                  "mesh", "untileable"])
+@pytest.mark.parametrize("case", ["window", "softcap", "mesh",
+                                  "untileable"])
 def test_xla_keeps_what_the_kernel_does_not_cover(case, long_at_256,
                                                   monkeypatch):
     cfg = configs.get_smoke("smollm-135m")
@@ -167,8 +167,6 @@ def test_xla_keeps_what_the_kernel_does_not_cover(case, long_at_256,
         return
     if case == "softcap":
         cfg = cfg.replace(logit_softcap=30.0)
-    elif case == "bf16_softmax":
-        cfg = cfg.replace(softmax_f32=False)
     elif case == "mesh":
         class FourDevices:
             size = 4

@@ -148,7 +148,7 @@ def test_recurrent_state_long_horizon_stability():
 
 
 def test_param_structs_match_init_shapes():
-    """ShapeDtypeStruct tree (dry-run input) must exactly mirror real
+    """ShapeDtypeStruct tree (lowering input) must exactly mirror real
     init_params shapes/dtypes for every arch."""
     key = jax.random.PRNGKey(0)
     for arch in configs.ARCHS:

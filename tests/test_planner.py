@@ -122,13 +122,3 @@ def test_block_size_planner():
     assert planner.plan_layer_blocks(32, 100.0, 400.0) == 8
     assert planner.plan_layer_blocks(32, 100.0, 1e9) == 1
     assert planner.plan_layer_blocks(32, 100.0, 0.0) == 1
-
-
-def test_autotune_picks_feasible_budget(setup):
-    from repro.core.autotune import autotune
-    params, x = setup
-    g = jax.grad(loss_fn)
-    tuned = autotune(g, params, x, fracs=(0.9, 0.6, 0.45))
-    assert tuned.plan.feasible
-    assert tuned.est_step_s > 0
-    assert 0.4 < tuned.budget_frac <= 0.9
