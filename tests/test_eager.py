@@ -1,4 +1,7 @@
 """Tests for the eager DTR executor: real buffers, real eviction, real remat."""
+import functools
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -247,3 +250,144 @@ def test_budgeted_gradients_bit_identical_to_unbudgeted(wallclock):
     assert ctx.timed_calls == (5 if wallclock else 0)   # one per op name
     for a, b in zip(free, got):
         assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+
+
+# ---------------------------------------------------------------------------
+# One compiled program per op signature, shared across contexts
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def programs(monkeypatch):
+    """A fresh process-wide program cache for one test."""
+    cache = executor._Programs()
+    monkeypatch.setattr(executor, "_PROGRAMS", cache)
+    return cache
+
+
+def test_fresh_lambda_compiles_once_across_contexts(programs):
+    n = 16 * 1024 // 4
+    for _ in range(2):
+        ctx = DTRContext(budget_bytes=5 * 16 * 1024)
+        vals = [ctx.wrap(jnp.linspace(0, 1, n))]
+        for i in range(24):      # a new lambda and a new name every call
+            vals.append(ctx.call(f"step{i}", lambda a: jnp.cos(a) * 1.01,
+                                 [vals[-1]])[0])
+        ctx.fetch(vals[5])
+        assert ctx.remat_runs > 0
+        assert ctx.compiled_calls == 24 + ctx.remat_runs
+        assert ctx.uncompiled_calls == 0
+    assert programs.compiles == 1
+    expect = np.linspace(0, 1, n)
+    for _ in range(5):
+        expect = np.cos(expect) * 1.01
+    np.testing.assert_allclose(np.asarray(vals[5].value), expect, rtol=1e-5)
+
+
+@pytest.mark.parametrize("lr1, lr2, dtype", [
+    (0.5, 0.25, jnp.float32),
+    (2, 2.0, jnp.int32),         # 2 keeps int32, 2.0 makes float32
+    (0.0, -0.0, jnp.float32),    # equal, yet -0.0 - 0.0 != -0.0 + 0.0
+])
+def test_closures_differing_in_a_captured_scalar_compile_apart(programs, lr1,
+                                                               lr2, dtype):
+    w0 = jnp.asarray([-0.0, 1.0, 2.0, 3.0]).astype(dtype)
+    g0 = jnp.full(4, 3, dtype)
+    ctx = DTRContext(budget_bytes=float("inf"))
+    w, g = ctx.wrap(w0), ctx.wrap(g0)
+
+    def sgd(lr):
+        return lambda w, g: w - lr * g
+
+    for lr in (lr1, lr2):
+        got = ctx.call("sgd", sgd(lr), [w, g])[0].value
+        want = w0 - lr * g0
+        assert got.dtype == want.dtype
+        assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    assert programs.compiles == 2 and ctx.compiled_calls == 2
+
+
+def test_untraceable_fn_falls_back_and_is_not_retraced(programs):
+    traces = []
+    real = programs._compile
+    programs._compile = lambda *a: traces.append(1) or real(*a)
+
+    def via_numpy(a):
+        return jnp.asarray(np.asarray(a) * 2)
+
+    ctx = DTRContext(budget_bytes=float("inf"))
+    x = ctx.wrap(jnp.arange(4.0))
+    for _ in range(2):
+        y = ctx.call("double", via_numpy, [x])[0]
+        np.testing.assert_array_equal(y.value, np.arange(4.0) * 2)
+    assert traces == [1] and programs.compiles == 0
+    assert ctx.uncompiled_calls == 2 and ctx.compiled_calls == 0
+
+
+class _Scale:
+    def __init__(self, s):
+        self.s = s
+
+    def apply(self, a):
+        return a * self.s
+
+
+@pytest.mark.parametrize("make_fn", [
+    lambda: (lambda w: lambda a: a * w)(np.arange(4.0)),   # over an array
+    lambda: (lambda s: lambda a: a * s[0])([2.0]),         # over a list
+    lambda: functools.partial(jnp.multiply, 2.0),          # a fresh partial
+    lambda: _Scale(2.0).apply,                             # a bound method
+], ids=["array", "list", "partial", "bound_method"])
+def test_uncacheable_fn_falls_back(programs, make_fn):
+    ctx = DTRContext(budget_bytes=float("inf"))
+    x = ctx.wrap(jnp.arange(4.0))
+    fn = make_fn()
+    y = ctx.call("scale", fn, [x])[0]
+    np.testing.assert_array_equal(y.value, fn(jnp.arange(4.0)))
+    assert ctx.uncompiled_calls == 1 and ctx.compiled_calls == 0
+    assert programs.compiles == 0 and not programs.entries
+
+
+def test_first_call_cost_excludes_compile_time(programs):
+    real = programs._compile
+
+    def slow_compile(*a):
+        time.sleep(0.5)
+        return real(*a)
+
+    programs._compile = slow_compile
+    ctx = DTRContext(budget_bytes=float("inf"))
+    ctx.call("scale", lambda a: a * 1.5, [ctx.wrap(jnp.ones(256))])
+    assert ctx.timed_calls == 1 and programs.compiles == 1
+    (cost,) = {o.cost for o in ctx.rt.ops.values()}
+    assert 0 < cost < 0.25
+
+
+def test_program_cache_is_bounded_and_ops_keep_their_programs(programs,
+                                                              monkeypatch):
+    monkeypatch.setattr(executor, "_MAX_PROGRAMS", 2)
+    n = 16 * 1024 // 4
+    ctx = DTRContext(budget_bytes=5 * 16 * 1024)
+    vals = [ctx.wrap(jnp.linspace(0, 1, n))]
+    for i in range(12):              # three programs, cycled
+        k = 1.0 + (i % 3) / 100
+        vals.append(ctx.call("step", lambda a, k=k: jnp.cos(a) * k,
+                             [vals[-1]])[0])
+        assert len(programs.entries) <= 2
+    ctx.fetch(vals[6])
+    assert ctx.remat_runs > 0 and ctx.uncompiled_calls == 0
+    expect = np.linspace(0, 1, n)
+    for i in range(6):
+        expect = np.cos(expect) * (1.0 + (i % 3) / 100)
+    np.testing.assert_allclose(np.asarray(vals[6].value), expect, rtol=1e-5)
+
+
+def test_closure_over_a_changing_value_stops_compiling(programs):
+    ctx = DTRContext(budget_bytes=float("inf"))
+    x = ctx.wrap(jnp.arange(4.0))
+    for step in range(10):
+        err = 0.1 * step         # a new value every step, as a loss is
+        y = ctx.call("scale", lambda a: err * a, [x])[0]
+        np.testing.assert_array_equal(y.value, err * jnp.arange(4.0))
+    assert programs.compiles == executor._MAX_VARIANTS
+    assert ctx.compiled_calls == executor._MAX_VARIANTS
+    assert ctx.uncompiled_calls == 10 - executor._MAX_VARIANTS
